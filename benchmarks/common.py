@@ -100,11 +100,11 @@ def streamed_hbm_bytes(spec, batch: int = 1, elem_bytes: int = 4,
     p = th * tw
     hs = s.bh * mh + th - mh
     ws = s.bw * mw + tw - mw
-    n_strips = batch * s.n_hb * s.n_wb
+    n_strips = batch * s.n_hb
     n_mb = s.m_pad // s.block_m
     read_x = n_strips * hs * ws * s.c_pad * n_mb * elem_bytes
     read_u = n_strips * p * s.c_pad * s.m_pad * filter_elem_bytes
-    write_y = batch * (s.n_hb * s.bh * mh) * (s.n_wb * s.bw * mw) \
+    write_y = batch * (s.n_hb * s.bh * mh) * (s.bw * mw) \
         * s.m_pad * elem_bytes
     return read_x + read_u + write_y
 
@@ -161,12 +161,12 @@ def separable_fused_hbm_bytes(spec, batch: int = 1, elem_bytes: int = 4,
     p = th * tw
     hs = s.bh * mh + th - mh
     ws = s.bw * mw + tw - mw
-    n_strips = batch * s.n_hb * s.n_wb
+    n_strips = batch * s.n_hb
     n_mb = s.m_pad // s.block_m
     read_x = n_strips * hs * ws * s.c_pad * n_mb * elem_bytes
     read_u_dw = n_strips * p * s.c_pad * n_mb * filter_elem_bytes
     read_u_pw = n_strips * s.c_pad * s.m_pad * filter_elem_bytes
-    write_y = batch * (s.n_hb * s.bh * mh) * (s.n_wb * s.bw * mw) \
+    write_y = batch * (s.n_hb * s.bh * mh) * (s.bw * mw) \
         * s.m_pad * elem_bytes
     return read_x + read_u_dw + read_u_pw + write_y
 
@@ -190,10 +190,10 @@ def separable_unfused_hbm_bytes(dw_spec, pw_mm: int, pw_k: int, pw_n: int,
     p = th * tw
     hs = s.bh * mh + th - mh
     ws = s.bw * mw + tw - mw
-    n_strips = batch * s.n_hb * s.n_wb
+    n_strips = batch * s.n_hb
     read_x = n_strips * hs * ws * s.c_pad * elem_bytes
     read_u_dw = n_strips * p * s.c_pad * filter_elem_bytes
-    write_z = batch * (s.n_hb * s.bh * mh) * (s.n_wb * s.bw * mw) \
+    write_z = batch * (s.n_hb * s.bh * mh) * (s.bw * mw) \
         * s.c_pad * elem_bytes
     bm_, bk_, bn_ = blocks
     mm_pad = -(-pw_mm // bm_) * bm_
@@ -222,11 +222,11 @@ def strided_streamed_hbm_bytes(spec, batch: int = 1, elem_bytes: int = 4,
     p4 = 4 * th * tw
     hs = 2 * (s.bh * mh + th - mh)
     ws = 2 * (s.bw * mw + tw - mw)
-    n_strips = batch * s.n_hb * s.n_wb
+    n_strips = batch * s.n_hb
     n_mb = s.m_pad // s.block_m
     read_x = n_strips * hs * ws * s.c_pad * n_mb * elem_bytes
     read_u = n_strips * p4 * s.c_pad * s.m_pad * filter_elem_bytes
-    write_y = batch * (s.n_hb * s.bh * mh) * (s.n_wb * s.bw * mw) \
+    write_y = batch * (s.n_hb * s.bh * mh) * (s.bw * mw) \
         * s.m_pad * elem_bytes
     return read_x + read_u + write_y
 

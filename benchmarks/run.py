@@ -118,6 +118,8 @@ def main(argv=None) -> None:
                          "span decomposition audit -- BENCH_PR10.json)")
     args = ap.parse_args(argv)
 
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (amortization, fast_fraction, per_layer, roofline,
                             serving, startup, whole_network)
 

@@ -162,6 +162,9 @@ def _worker(args) -> dict:
 
 def _spawn(n: int, args, artifact: str | None) -> dict:
     env = dict(os.environ)
+    # forced host devices, not chips: pin the CPU backend so a worker never
+    # reaches for (or waits on) an accelerator the parent's host may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.pathsep.join(
         [_ROOT, os.path.join(_ROOT, "src")]

@@ -14,13 +14,16 @@ For each network the harness measures
     parity gate);
   * steady-state latency of the compiled plan vs the im2row baseline.
 
+One process holds a chip at a time, so the parent never imports JAX: each
+network's compile/save/load/timing half runs in one child, and the
+fresh-process reload in a second child after the first has exited.
+
   PYTHONPATH=src python -m benchmarks.startup --out BENCH_PR5.json
 """
 
 from __future__ import annotations
 
 import argparse
-import hashlib
 import json
 import os
 import subprocess
@@ -28,17 +31,9 @@ import sys
 import tempfile
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core.compile import NetworkPlan, compile as compile_network
-from repro.core.plan import clear_plan_cache, plan_cache_info
-from repro.models import cnn
-
-from benchmarks.common import bench_metadata, time_jitted
-
 NETWORKS = ["mobilenet_v2", "vgg16"]
+_MARK = "STARTUP_JSON "
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The subprocess half of the fresh-process parity gate: load the artifact,
 # run the deterministic input, print the output digest. No access to specs
@@ -61,12 +56,35 @@ print(hashlib.sha256(y.tobytes()).hexdigest())
 """
 
 
-def _digest(y) -> str:
-    return hashlib.sha256(np.asarray(y).tobytes()).hexdigest()
+def _child(args: list[str]) -> str:
+    """Run a Python child from the repo root; its stdout."""
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(
+        p for p in (os.path.join(_ROOT, "src"), _ROOT,
+                    os.environ.get("PYTHONPATH")) if p)}
+    out = subprocess.run([sys.executable, *args], capture_output=True,
+                         text=True, env=env, cwd=_ROOT)
+    assert out.returncode == 0, out.stderr
+    return out.stdout
 
 
 def bench_startup(net: str, res: int, iters: int, warmup: int,
                   artifact_dir: str) -> dict:
+    """The compile half, run in a child: cold compile, save, warm load,
+    in-process parity, and steady-state timing."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.common import bench_metadata, time_jitted
+    from repro.core.compile import NetworkPlan, compile as compile_network
+    from repro.core.plan import clear_plan_cache, plan_cache_info
+    from repro.models import cnn
+
+    def _digest(y) -> str:
+        return hashlib.sha256(np.asarray(y).tobytes()).hexdigest()
+
     specs = cnn.NETWORKS[net][0]()
     params = cnn.init_cnn(jax.random.key(0), specs, 3, res=res)
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
@@ -91,21 +109,12 @@ def bench_startup(net: str, res: int, iters: int, warmup: int,
     warm_s = time.perf_counter() - t0
     assert plan_cache_info()["artifact_hits"] == 1
 
-    # in-process parity must be bitwise; fresh-process parity must match it.
+    # in-process parity must be bitwise; the parent checks fresh-process
+    # parity against output_sha256.
     y_cold = plan.apply(x)
     y_warm = loaded.apply(x)
     assert np.array_equal(np.asarray(y_cold), np.asarray(y_warm)), \
         "save/load round-trip is not bitwise identical"
-    child = subprocess.run(
-        [sys.executable, "-c", _CHILD, path, str(res)],
-        capture_output=True, text=True,
-        env={**os.environ,
-             "PYTHONPATH": os.pathsep.join(
-                 p for p in ("src", os.environ.get("PYTHONPATH")) if p)})
-    assert child.returncode == 0, child.stderr
-    fresh_digest = child.stdout.strip().splitlines()[-1]
-    assert fresh_digest == _digest(y_cold), \
-        (fresh_digest, _digest(y_cold))
 
     fn_planned = jax.jit(loaded.apply)
     fn_base = jax.jit(lambda x: cnn.cnn_forward(params, x, specs,
@@ -118,8 +127,8 @@ def bench_startup(net: str, res: int, iters: int, warmup: int,
             "warm_load_s": warm_s, "artifact_bytes": artifact_bytes,
             "startup_speedup": cold_s / warm_s,
             "t_planned_s": t_planned, "t_im2row_s": t_base,
-            "fresh_process_parity": True,
-            "output_sha256": _digest(y_cold)}
+            "artifact": path, "output_sha256": _digest(y_cold),
+            "meta": bench_metadata()}
 
 
 def main(argv=None):
@@ -131,16 +140,35 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--warmup", type=int, default=1)
     ap.add_argument("--out", default=None)
+    ap.add_argument("--worker", default=None, metavar="DIR",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
-    rows = []
+    if args.worker:
+        for net in args.networks:
+            print(_MARK + json.dumps(bench_startup(
+                net, args.res, args.iters, args.warmup, args.worker)),
+                flush=True)
+        return None
+
+    rows, meta = [], None
     print("== cold compile vs warm artifact startup (compile/save/load) ==")
     print(f"{'Network':14s} {'cold(ms)':>9s} {'save(ms)':>9s} "
           f"{'warm(ms)':>9s} {'x-faster':>8s} {'MB':>6s} "
           f"{'planned(ms)':>12s} {'im2row(ms)':>11s}")
     with tempfile.TemporaryDirectory() as tmp:
         for net in args.networks:
-            r = bench_startup(net, args.res, args.iters, args.warmup, tmp)
+            out = _child(["-m", "benchmarks.startup", "--worker", tmp,
+                          "--networks", net, "--res", str(args.res),
+                          "--iters", str(args.iters),
+                          "--warmup", str(args.warmup)])
+            r = json.loads(next(ln for ln in out.splitlines()
+                                if ln.startswith(_MARK))[len(_MARK):])
+            fresh = _child(["-c", _CHILD, r.pop("artifact"),
+                            str(args.res)]).strip().splitlines()[-1]
+            assert fresh == r["output_sha256"], (fresh, r["output_sha256"])
+            r["fresh_process_parity"] = True
+            meta = r.pop("meta")
             rows.append(r)
             print(f"{r['network']:14s} {r['cold_compile_s']*1e3:9.1f} "
                   f"{r['save_s']*1e3:9.1f} {r['warm_load_s']*1e3:9.1f} "
@@ -150,7 +178,7 @@ def main(argv=None):
                   f"{r['t_im2row_s']*1e3:11.1f}", flush=True)
     if args.out:
         with open(args.out, "w") as f:
-            json.dump({"config": "startup", "meta": bench_metadata(),
+            json.dump({"config": "startup", "meta": meta,
                        "res": args.res, "rows": rows}, f, indent=1)
     return rows
 

@@ -22,6 +22,7 @@ import jax
 
 from repro.models import cnn
 from repro.runtime import inject
+from repro.runtime.cache import enable_compile_cache
 from repro.runtime.serve import ServeConfig, Server
 
 
@@ -35,6 +36,7 @@ def main():
                     help="artifact dir (default: a temp dir -- pass a real "
                          "path and re-run to see the warm start)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     specs_fn, _ = cnn.NETWORKS[args.net]
     specs = specs_fn()

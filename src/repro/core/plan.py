@@ -33,7 +33,9 @@ builds plans at init/weight-load time and executes them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
+import logging
 import os
 import time
 import typing
@@ -63,6 +65,8 @@ from repro.core.transforms import (DEFAULT_OUTPUT_TILE, CookToom, cook_toom,
 # XLA op (_epilogue_jnp).
 from repro.kernels.runtime import ACTIVATIONS as EPILOGUE_ACTIVATIONS
 from repro.kernels.runtime import epilogue_jnp as _epilogue_jnp
+
+_LOG = logging.getLogger(__name__)
 
 Algorithm = Literal["auto", "auto_tuned", "winograd", "winograd_f63", "fft",
                     "im2col", "pallas_winograd",
@@ -303,12 +307,13 @@ def _count_cache(hit: bool) -> None:
         _obs_metrics.count("plan.cache.miss")
 
 
-def _measure_allowed() -> bool:
-    """Measured autotuning needs concrete execution: it is disabled inside an
-    active jit trace and via REPRO_PLAN_NO_MEASURE."""
+def _measure_allowed(w) -> bool:
+    """Measured autotuning needs concrete execution: it is disabled when the
+    filter being planned is a tracer (planning inside a jit trace) and via
+    REPRO_PLAN_NO_MEASURE."""
     if os.environ.get("REPRO_PLAN_NO_MEASURE"):
         return False
-    return jax.core.trace_state_clean()
+    return not isinstance(w, jax.core.Tracer)
 
 
 # ---------------------------------------------------------------------------
@@ -485,21 +490,27 @@ def _build_spec(x_shape, w_shape, dtype, stride, padding, requested,
         geom = _wg.conv2d_strided_geometry(h, w, kh, kw, mh, mw, padding)
         strided = dict(algorithm=resolved, output_tile=(mh, mw), ct_h=ct_h,
                        ct_w=ct_w, geometry=geom, **base)
+        if resolved == "winograd_strided":
+            return ConvSpec(**strided)
+        chooser = (_wg.stream_geometry_depthwise
+                   if resolved == "pallas_depthwise_strided" else
+                   functools.partial(_wg.stream_geometry, mout=mout))
+        try:
+            stream = chooser(geom.n_h, geom.n_w, c, ct_h=ct_h, ct_w=ct_w,
+                             phases=4, input_stride=2)
+        except ValueError:
+            # the default F(4, .) tile's four phase banks can outgrow VMEM
+            # (a 3-channel stem pads to a 128-lane filter block); the
+            # default then steps down to F(2, .). A pinned tile raises.
+            if output_tile is not None or (mh, mw) == (2, 2):
+                raise
+            return _build_spec(x_shape, w_shape, dtype, stride, padding,
+                               requested, resolved, 2, groups, layout,
+                               compute_dtype)
+        blocks = (stream.bh * stream.bw, stream.block_c)
         if resolved == "pallas_winograd_strided":
-            stream = _wg.stream_geometry(geom.n_h, geom.n_w, c, mout,
-                                         ct_h, ct_w, phases=4,
-                                         input_stride=2)
-            return ConvSpec(stream=stream,
-                            blocks=(stream.bh * stream.bw, stream.block_c,
-                                    stream.block_m), **strided)
-        if resolved == "pallas_depthwise_strided":
-            stream = _wg.stream_geometry_depthwise(geom.n_h, geom.n_w, c,
-                                                   ct_h, ct_w, phases=4,
-                                                   input_stride=2)
-            return ConvSpec(stream=stream,
-                            blocks=(stream.bh * stream.bw, stream.block_c),
-                            **strided)
-        return ConvSpec(**strided)
+            blocks += (stream.block_m,)
+        return ConvSpec(stream=stream, blocks=blocks, **strided)
 
     if resolved in ("winograd_depthwise", "winograd_grouped"):
         mh, mw = _resolve_output_tile(kh, kw, output_tile)
@@ -1113,6 +1124,11 @@ def _measure_autotune(x_shape, w_shape, dtype, stride, padding,
         except Exception:
             if label in ("winograd", "im2col"):
                 raise  # the two contenders every eligible layer must have
+            # a dropped contender is a fault to see, not a silent skip
+            _obs_metrics.count("plan.autotune.contender_failed")
+            _LOG.warning("autotune contender %s (%s) failed on %sx%s; "
+                         "dropped from the race", label, alg, x_shape,
+                         w_shape, exc_info=True)
             continue
         times[label] = (t, spec.algorithm, spec.output_tile, cd)
     win = min(times, key=lambda k: times[k][0])
@@ -1268,7 +1284,7 @@ def plan_conv2d(
             elif (tuned := _tuningdb_lookup(
                     x_shape, w_shape, dtype_str, stride, padding, groups,
                     data_format, "auto" if dtype_race else compute_dtype,
-                    output_tile)) is not None or _measure_allowed():
+                    output_tile)) is not None or _measure_allowed(w):
                 if tuned is not None:
                     # fleet tuning database: adopt the recorded winner,
                     # tile, dtype, and evidence -- zero local

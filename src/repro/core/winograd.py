@@ -169,50 +169,102 @@ def conv2d_strided_geometry(h: int, w: int, kh: int, kw: int, mh: int,
 
 
 class StreamGeometry(NamedTuple):
-    """Halo-blocking geometry for the region-streaming Pallas kernel
-    (kernels/winograd.py:winograd_streamed), derived once at plan time.
+    """Halo-blocking geometry for the region-streaming Pallas kernels
+    (kernels/winograd.py, kernels/depthwise.py), derived once at plan time.
 
-    The kernel's grid walks (n_hb, n_wb) blocks of (bh, bw) output tiles;
-    each grid cell reads one overlapping halo strip of the padded input
-    (origin stride bh*mh / bw*mw, extent k-1 larger) and writes one
-    non-overlapping (bh*mh, bw*mw) NHWC output block. Edge blocks are
-    covered by padding the input up to n_hb*bh / n_wb*bw whole tile blocks
-    (`pad_h` / `pad_w` extra rows/cols beyond the convolution padding);
-    the surplus outputs are cropped after the kernel.
+    The kernel's grid walks n_hb strips of (bh, bw) output tiles, where a
+    strip spans the whole width (bw = n_w); each grid cell reads one
+    overlapping halo strip of the padded input (origin stride bh*mh rows,
+    extent k-1 rows larger) and writes one non-overlapping (bh*mh, bw*mw)
+    NHWC output block. Edge strips are covered by padding the input up to
+    n_hb*bh whole tile rows (`pad_h` extra rows beyond the convolution
+    padding); the surplus outputs are cropped after the kernel.
     """
 
     bh: int           # output-tile rows per grid cell
-    bw: int           # output-tile cols per grid cell
+    bw: int           # output-tile cols per grid cell (the whole width)
     n_hb: int         # grid extent along H  (= ceil(n_h / bh))
-    n_wb: int         # grid extent along W  (= ceil(n_w / bw))
     pad_h: int        # extra rows of input padding for edge blocks
-    pad_w: int        # extra cols of input padding for edge blocks
     block_c: int      # Pallas channel block
     block_m: int      # Pallas output-channel block
     c_pad: int        # C rounded up to block_c
     m_pad: int        # M rounded up to block_m
 
 
-#: Per-strip fixed cost in tile-equivalents for the stream_geometry score:
-#: each (i, j) grid strip pays DMA setup / loop overhead on top of its
-#: per-tile compute, so blockings that shatter the image into many small
-#: strips lose to slightly-wasteful large strips.
+#: Per-grid-step fixed cost in tile-equivalents for the strip score: each
+#: grid step pays DMA setup / loop overhead on top of its per-tile compute,
+#: so blockings that shatter the image into many small strips lose to
+#: slightly-wasteful large strips.
 _STRIP_OVERHEAD_TILES = 16
+
+
+def _sub(d: int) -> int:
+    """Round up to the 8-row sublane tile of an fp32 VMEM buffer."""
+    return -(-d // 8) * 8
+
+
+def _lane(d: int) -> int:
+    """Round up to the 128-lane tile of an fp32 VMEM buffer."""
+    return -(-d // 128) * 128
+
+
+def _strip_blocking(n_h: int, n_w: int, ct_h: CookToom, ct_w: CookToom,
+                    input_stride: int, steps_per_strip: int, vmem_of,
+                    vmem_budget_bytes: int, what: str) -> tuple[int, int]:
+    """(bh, n_hb): the strip height of a full-width halo-strip grid.
+
+    Strips span the whole padded width (bw = n_w, one strip column): the
+    TPU compiler needs a block's second-minor extent to be a multiple of 8
+    or the whole axis, and a halo strip of width bw*mw + k - 1 can only be
+    the latter. Candidate heights are scored by padded tile count
+    (edge-block compute waste) plus a fixed per-grid-step overhead (many
+    tiny strips lose), tie-broken toward larger region blocks (bigger
+    point-GEMMs); `vmem_of(bh, hs, ws)` is the kernel's VMEM working set in
+    bytes, and candidates over the budget are discarded."""
+    th, tw, mh, mw = ct_h.t, ct_w.t, ct_h.m, ct_w.m
+    bw = max(n_w, 1)
+    ws = input_stride * (bw * mw + tw - mw)
+    best = None
+    for bh in sorted({b for b in (1, 2, 4, 8, 16) if b <= max(n_h, 1)}
+                     | {b for b in range(1, 17) if n_h % b == 0}):
+        if bh * bw > 256:
+            continue
+        hs = input_stride * (bh * mh + th - mh)
+        if vmem_of(bh, hs, ws) > vmem_budget_bytes:
+            continue
+        n_hb = -(-n_h // bh)
+        score = (n_hb * bh * bw + _STRIP_OVERHEAD_TILES * n_hb
+                 * steps_per_strip, -bh)
+        if best is None or score < best[0]:
+            best = (score, (bh, n_hb))
+    if best is None:
+        raise ValueError(
+            f"no halo blocking of the ({n_h}, {n_w})-tile grid ({what}, "
+            f"t=({th}, {tw})) fits the {vmem_budget_bytes >> 20} MiB VMEM "
+            f"budget with full-width strips; use a smaller output_tile")
+    return best[1]
 
 
 def stream_geometry(n_h: int, n_w: int, c: int, mout: int,
                     ct_h: CookToom, ct_w: CookToom, *,
                     phases: int = 1, input_stride: int = 1,
                     vmem_budget_bytes: int = 15 * 2 ** 20) -> StreamGeometry:
-    """Choose the halo blocking for one layer, once, at plan time.
+    """Choose the halo blocking of the dense streamed kernels (and the fused
+    separable block), once, at plan time: full-width strips of a height
+    chosen by `_strip_blocking`.
 
-    Candidate (bh, bw) tile-block shapes are scored by estimated cost:
-    padded tile count (edge-block compute waste) plus a fixed per-strip
-    overhead term (many tiny strips lose), tie-broken toward larger region
-    blocks (bigger point-GEMMs). Candidates that do not fit the VMEM budget
-    (halo strip + filter block double-buffered, fp32 accumulator,
-    transformed-input cache, transform transient, output block) are
-    discarded.
+    The kernels move strips and output blocks with strided loads and
+    stores, which the TPU compiler takes only on refs at most one 128-lane
+    vreg wide, and it cannot window a strip narrower than 128 lanes at all.
+    So the input channel block is exactly 128 (C pads up to it) and the
+    output channel block is at most 128.
+
+    The VMEM estimate counts what the kernel allocates, every block rounded
+    to the (8, 128) fp32 tile: the halo strip, filter block and output
+    block double-buffered (a filter of one block, single-buffered), the
+    fp32 accumulator, the transformed-input cache, and the transform's live
+    slabs. It is held to 15 MiB against the compiler's 16 MiB scoped-VMEM
+    default.
 
     `phases`/`input_stride` describe the stride-2 phase-decomposition
     kernels: the halo strip spans `input_stride`x more input per axis and
@@ -221,63 +273,29 @@ def stream_geometry(n_h: int, n_w: int, c: int, mout: int,
     """
     th, tw, mh, mw = ct_h.t, ct_w.t, ct_h.m, ct_w.m
     p = th * tw
-    c_ref = -(-c // _stream_block(c, 128)) * _stream_block(c, 128)
-    m_ref = -(-mout // _stream_block(mout, 128)) * _stream_block(mout, 128)
+    pp = p * phases     # Winograd points across all phases
+    bc, bm = 128, _stream_block(mout, 128)
+    c_pad, m_pad = _lane(c), -(-mout // bm) * bm
+    n_cb = c_pad // bc
+    bw = max(n_w, 1)
+    # a filter of one block is single-buffered by the kernel
+    filter_bufs = 1 if (c_pad, m_pad) == (bc, bm) else 2
 
-    def tile_candidates(n_tiles: int) -> list[int]:
-        cand = {b for b in (1, 2, 4, 8, 16) if b <= max(n_tiles, 1)}
-        cand |= {b for b in range(1, 17) if n_tiles % b == 0}
-        return sorted(cand)
+    def vmem_of(bh, hs, ws):
+        br = _sub(bh * bw)
+        return 4 * (2 * hs * _sub(ws) * bc                # halo strip
+                    + filter_bufs * pp * bc * _lane(bm)   # filter block
+                    + p * br * _lane(bm)                  # accumulator
+                    + n_cb * pp * br * bc                 # v cache
+                    + 2 * bh * mh * _sub(bw * mw) * _lane(bm)  # output
+                    + (th + 2) * br * bc)                 # live slabs
 
-    def chan_candidates(dim: int) -> list[int]:
-        cand = {_stream_block(dim, 128)}
-        if dim > 128:
-            cand.add(256)               # fewer, fatter grid steps when it fits
-        return sorted(cand)
-
-    best = None
-    for bc in chan_candidates(c):
-        c_pad = -(-c // bc) * bc
-        for bm in chan_candidates(mout):
-            m_pad = -(-mout // bm) * bm
-            n_cb, n_mb = c_pad // bc, m_pad // bm
-            for bh in tile_candidates(n_h):
-                for bw in tile_candidates(n_w):
-                    n_hb, n_wb = -(-n_h // bh), -(-n_w // bw)
-                    br = bh * bw
-                    if br > 256:
-                        continue
-                    hs = input_stride * (bh * mh + th - mh)
-                    ws = input_stride * (bw * mw + tw - mw)
-                    pp = p * phases     # Winograd points across all phases
-                    vmem = 4 * (2 * hs * ws * bc    # halo strip (x2 buffer)
-                                + 2 * pp * bc * bm  # filter block (x2 buffer)
-                                + p * br * bm       # fp32 accumulator
-                                + pp * br * c_pad   # transformed-input cache
-                                + pp * br * bc      # transform transient
-                                + bh * mh * bw * mw * bm)   # output block
-                    if vmem > vmem_budget_bytes:
-                        continue
-                    # work: padded tiles, scaled by any extra C/M padding
-                    # this blocking forces; overhead: fixed cost per grid
-                    # step (tiny steps lose to slightly-wasteful fat ones).
-                    work = (n_hb * bh * n_wb * bw * c_pad * m_pad
-                            / (c_ref * m_ref))
-                    steps = n_hb * n_wb * n_cb * n_mb
-                    score = (work + _STRIP_OVERHEAD_TILES * steps, -br, -bc)
-                    if best is None or score < best[0]:
-                        best = (score, (bh, bw, n_hb, n_wb, bc, bm,
-                                        c_pad, m_pad))
-    if best is None:
-        raise ValueError(
-            f"no halo blocking of the ({n_h}, {n_w})-tile grid (C={c}, "
-            f"M={mout}, t=({ct_h.t}, {ct_w.t}), phases={phases}) fits the "
-            f"{vmem_budget_bytes >> 20} MiB VMEM budget; use a smaller "
-            f"output_tile")
-    bh, bw, n_hb, n_wb, bc, bm, c_pad, m_pad = best[1]
-    return StreamGeometry(bh=bh, bw=bw, n_hb=n_hb, n_wb=n_wb,
+    bh, n_hb = _strip_blocking(n_h, n_w, ct_h, ct_w, input_stride,
+                               n_cb * (m_pad // bm), vmem_of,
+                               vmem_budget_bytes, f"C={c}, M={mout}, "
+                               f"phases={phases}")
+    return StreamGeometry(bh=bh, bw=bw, n_hb=n_hb,
                           pad_h=(n_hb * bh - n_h) * mh,
-                          pad_w=(n_wb * bw - n_w) * mw,
                           block_c=bc, block_m=bm, c_pad=c_pad, m_pad=m_pad)
 
 
@@ -287,19 +305,32 @@ def stream_geometry_depthwise(n_h: int, n_w: int, c: int,
                               mult: int = 1,
                               vmem_budget_bytes: int = 15 * 2 ** 20
                               ) -> StreamGeometry:
-    """Halo blocking for the streamed depthwise kernel: reuse the dense
-    chooser (same strip-origin / edge-padding / per-strip-overhead model;
-    its dense VMEM estimate upper-bounds the depthwise kernel's working set,
-    which has no filter blocks or cross-C accumulator) with the output
-    channel axis collapsed onto the channel axis -- depthwise walks ONE
-    channel axis, so block_m is pinned to block_c. A channel multiplier > 1
-    widens the taps and output block by `mult`; folding it into the phase
-    count keeps the VMEM estimate an upper bound without a second model."""
-    g = stream_geometry(n_h, n_w, c, c, ct_h, ct_w,
-                        phases=phases * mult,
-                        input_stride=input_stride,
-                        vmem_budget_bytes=vmem_budget_bytes)
-    return g._replace(block_m=g.block_c, m_pad=g.c_pad)
+    """Halo blocking of the streamed depthwise kernels: the same full-width
+    strips and 128-lane channel block as `stream_geometry`, with one
+    channel axis (block_m = block_c). The VMEM estimate is the depthwise
+    kernel's own: the halo strip, the (points, mult, 128) taps and the
+    `mult` output planes double-buffered, the per-point Hadamard products'
+    scratch, and the transform's live slabs."""
+    th, tw, mh, mw = ct_h.t, ct_w.t, ct_h.m, ct_w.m
+    p = th * tw
+    c_pad = _lane(c)
+    bw = max(n_w, 1)
+
+    def vmem_of(bh, hs, ws):
+        br = _sub(bh * bw)
+        return 4 * 128 * (2 * hs * _sub(ws)                  # halo strip
+                          + 2 * p * phases * _sub(mult)      # taps
+                          + 2 * mult * bh * mh * _sub(bw * mw)   # output
+                          + p * br                           # products
+                          + (th + 2) * br)                   # live slabs
+
+    bh, n_hb = _strip_blocking(n_h, n_w, ct_h, ct_w, input_stride,
+                               c_pad // 128, vmem_of, vmem_budget_bytes,
+                               f"C={c}, mult={mult}, phases={phases}")
+    return StreamGeometry(bh=bh, bw=bw, n_hb=n_hb,
+                          pad_h=(n_hb * bh - n_h) * mh,
+                          block_c=128, block_m=128, c_pad=c_pad,
+                          m_pad=c_pad)
 
 
 class Axis1DGeometry(NamedTuple):

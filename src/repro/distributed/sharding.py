@@ -24,20 +24,13 @@ from repro.models.config import ArchConfig
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = True):
-    """Version-portable shard_map.
+    """`jax.shard_map` with the replication check spelled `check_replication`.
 
-    jax >= 0.5 exposes `jax.shard_map` (replication-check kwarg `check_vma`);
-    the 0.4.x line keeps `jax.experimental.shard_map.shard_map` (kwarg
-    `check_rep`). Everything in this repo (and its spawned-subprocess test
-    snippets) should route through this shim instead of touching either
-    attribute directly.
+    Everything in this repo (and its spawned-subprocess test snippets) routes
+    through this wrapper instead of calling `jax.shard_map` directly.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_replication)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 def _guard(mesh: Mesh, shape: tuple, spec: P) -> P:

@@ -43,8 +43,7 @@ def conv1d_ct_fused(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (B, S, m, C) output tiles. S % block_s == 0, C % block_c == 0.
-    `interpret=None` resolves via the shared REPRO_PALLAS_COMPILE-aware rule
-    (kernels.runtime)."""
+    `interpret=None` resolves via the shared rule (kernels.runtime)."""
     interpret = resolve_interpret(interpret)
     b, s, t, c = tiles.shape
     assert t == ct.t and u.shape == (t, c)

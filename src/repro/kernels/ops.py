@@ -2,9 +2,9 @@
 
 These mirror the pure-JAX entry points in repro.core (same signatures, same
 semantics) and handle all padding/blocking so callers never see alignment
-constraints. `interpret` defaults to the shared REPRO_PALLAS_COMPILE-aware
-rule in repro.kernels.runtime: interpret off-TPU (this container is CPU-only;
-on a real TPU pass interpret=False or set REPRO_PALLAS_COMPILE=1).
+constraints. `interpret` defaults to the shared rule in
+repro.kernels.runtime: compiled kernels on a TPU backend, interpret mode
+elsewhere.
 
 The planned Winograd path streams regions end-to-end inside the kernel
 (winograd_conv2d_planned -> kernels.winograd.winograd_streamed): the only
@@ -85,7 +85,7 @@ def winograd_conv2d_planned(
     c = x.shape[3]
     xp = jnp.pad(x, ((0, 0),
                      (geometry.lo_h, geometry.hi_h + stream.pad_h),
-                     (geometry.lo_w, geometry.hi_w + stream.pad_w),
+                     (geometry.lo_w, geometry.hi_w),
                      (0, stream.c_pad - c)))
     y = _k_winograd.winograd_streamed(
         xp, u, _pad_bias(bias, stream.m_pad), scale, ct_h=ct_h, ct_w=ct_w,
@@ -154,7 +154,7 @@ def winograd_strided_conv2d_planned(
     c = x.shape[3]
     xp = jnp.pad(x, ((0, 0),
                      (geometry.lo_h, geometry.hi_h + 2 * stream.pad_h),
-                     (geometry.lo_w, geometry.hi_w + 2 * stream.pad_w),
+                     (geometry.lo_w, geometry.hi_w),
                      (0, stream.c_pad - c)))
     y = _k_winograd.winograd_strided_streamed(
         xp, u, _pad_bias(bias, stream.m_pad), scale, ct_h=ct_h, ct_w=ct_w,
@@ -184,7 +184,7 @@ def depthwise_strided_conv2d_planned(
     c = x.shape[3]
     xp = jnp.pad(x, ((0, 0),
                      (geometry.lo_h, geometry.hi_h + 2 * stream.pad_h),
-                     (geometry.lo_w, geometry.hi_w + 2 * stream.pad_w),
+                     (geometry.lo_w, geometry.hi_w),
                      (0, stream.c_pad - c)))
     y = _k_depthwise.depthwise_strided_streamed(
         xp, u, _pad_bias(bias, stream.c_pad), scale, ct_h=ct_h, ct_w=ct_w,
@@ -281,7 +281,7 @@ def depthwise_conv2d_planned(
     mult = u.shape[2]
     xp = jnp.pad(x, ((0, 0),
                      (geometry.lo_h, geometry.hi_h + stream.pad_h),
-                     (geometry.lo_w, geometry.hi_w + stream.pad_w),
+                     (geometry.lo_w, geometry.hi_w),
                      (0, stream.c_pad - c)))
     y = _k_depthwise.depthwise_streamed(
         xp, u, _pad_bias(bias, stream.c_pad * mult), scale, ct_h=ct_h,
@@ -314,7 +314,7 @@ def separable_conv2d_planned(
     c = x.shape[3]
     xp = jnp.pad(x, ((0, 0),
                      (geometry.lo_h, geometry.hi_h + stream.pad_h),
-                     (geometry.lo_w, geometry.hi_w + stream.pad_w),
+                     (geometry.lo_w, geometry.hi_w),
                      (0, stream.c_pad - c)))
     y = _k_depthwise.separable_streamed(
         xp, u_dw, u_pw, _pad_bias(bias_dw, stream.c_pad),

@@ -3,9 +3,8 @@ epilogue vocabulary.
 
 Every Pallas entry point (kernels/*.py and the ops.py wrappers) resolves its
 `interpret` argument through `resolve_interpret`, so direct kernel callers and
-the wrapped paths follow the same REPRO_PALLAS_COMPILE-aware rule: interpret
-off-TPU (this container is CPU-only), compile on TPU or when the env var
-forces it.
+the wrapped paths follow the same rule: compiled kernels on a TPU backend,
+interpret mode on any other (the CPU test runs).
 
 `apply_activation` is the epilogue vocabulary shared by the Winograd and GEMM
 kernels (bias add + none/relu/relu6/gelu) and by the pure-JAX executors, so

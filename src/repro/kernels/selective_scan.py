@@ -21,8 +21,8 @@ HBM traffic therefore = inputs + outputs = B*L*(2 bD + 2N)*bytes per D-block,
 i.e. the N-fold state expansion and the log-levels never leave VMEM. At
 falcon train shapes that is ~130 GB/device/step vs 78 TB -- a ~600x cut on
 the scan's share (the roofline accounting for the TPU path is derived
-analytically in EXPERIMENTS.md; this container is CPU-only so the kernel
-validates in interpret mode).
+analytically in EXPERIMENTS.md; the CPU tests validate the kernel in
+interpret mode).
 
 Channels ride the 128-lane axis (bD a multiple of 128), N on sublanes --
 the paper's channels-innermost argument, applied to the SSM state.

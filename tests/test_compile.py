@@ -485,3 +485,28 @@ def test_legacy_warns_once_per_process():
     with _w.catch_warnings():
         _w.simplefilter("error", DeprecationWarning)
         cnn.plan_cnn(params, specs[:1], res=32)   # second call: silent
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_directory(from_env, tmp_path, monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (nothing is set in code), else to the fixed in-checkout path."""
+    from repro.runtime import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    # setenv records the original value, so the helper's own export of the
+    # default is undone after the test; an empty value reads as unset.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path) if from_env else "")
+    try:
+        got = cache.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

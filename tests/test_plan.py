@@ -162,6 +162,35 @@ def test_auto_tuned_measures_once_and_caches_winner(rng):
     assert p2.spec is p.spec
 
 
+def test_auto_tuned_counts_and_logs_a_failed_contender(rng, monkeypatch,
+                                                       caplog):
+    """A contender that raises is dropped from the race, but counted
+    (plan.autotune.contender_failed) and logged with its exception."""
+    from repro.core import plan as plan_mod
+    from repro.obs import metrics
+
+    real = plan_mod._build_spec
+
+    def build(x_shape, w_shape, dtype, stride, padding, requested, resolved,
+              *args, **kwargs):
+        if resolved == "fft":
+            raise RuntimeError("fft contender refused")
+        return real(x_shape, w_shape, dtype, stride, padding, requested,
+                    resolved, *args, **kwargs)
+
+    monkeypatch.setattr(plan_mod, "_build_spec", build)
+    counter = metrics.registry().counter("plan.autotune.contender_failed")
+    before = counter.value
+    w = jnp.asarray(rng.standard_normal((3, 3, 8, 8)) / 3, jnp.float32)
+    with caplog.at_level("WARNING", logger=plan_mod.__name__):
+        p = plan_conv2d((1, 20, 20, 8), w, algorithm="auto_tuned")
+    assert counter.value == before + 1
+    assert "t_fft_s" not in p.spec.autotune_report
+    assert p.algorithm != "fft"
+    assert any("fft" in r.getMessage() and r.exc_info
+               for r in caplog.records)
+
+
 def test_auto_tuned_falls_back_to_heuristic_under_jit(rng):
     """Planning inside a jit trace cannot measure; the static amortization
     predicate decides instead, and tracing must not crash."""
