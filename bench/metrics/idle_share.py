@@ -1,0 +1,9 @@
+"""idle_share: the share of the traced window in which no operation ran
+on the device (the union of the device's op intervals), from the
+profiler trace."""
+
+
+def read(run):
+    if not run.trace or not run.trace["window_s"]:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
