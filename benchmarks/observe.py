@@ -11,8 +11,8 @@ percent, boolean gates) compare across machines, and CI can gate a fresh
 run against the committed baseline (benchmarks/regress.py).
 
 The enabled arm's trace is then audited: for every request, the four
-profiler spans (queue_wait -> batch_formation -> dispatch -> respond)
-must tile [submit, finish], so their sum is checked against the
+intervals (queue_wait -> batch_formation -> its batch's dispatch ->
+respond) must tile [submit, finish], so their sum is checked against the
 independently measured ticket latency (max residual gated < 1%). The
 chrome://tracing export and the process metrics snapshot are written
 next to the JSON for CI artifact upload.
@@ -23,7 +23,7 @@ Artifact format "repro.observe/v1":
     span_table: named spans of one request (EXPERIMENTS.md table)
     trace_events: event count of the chrome export
     gates: {overhead_lt_10pct, decomposition_residual_lt_1pct,
-            valid_chrome_trace, layer_spans_present}
+            valid_chrome_trace, phase_spans_present}
 """
 
 from __future__ import annotations
@@ -88,35 +88,23 @@ def _request_decomposition(tracer):
 
 
 def _span_table(tracer, rid):
-    """The named spans of one request, plus the layer children of its
-    dispatch interval -- the EXPERIMENTS.md table."""
+    """The named spans of one request, plus the live phase spans of its
+    batch between batch selection and the request's finish -- the
+    EXPERIMENTS.md table."""
     spans = tracer.spans()
     mine = [s for s in spans if s.args.get("rid") == rid]
     if not mine:
         return []
-    bf = next((s for s in mine if s.name == "serve.batch_formation"), None)
-    rows = [{"span": s.name, "ms": round((s.t1 - s.t0) * 1e3, 4),
-             **({"executor": s.args["executor"]}
-                if "executor" in s.args else {})}
+    rows = [{"span": s.name, "ms": round((s.t1 - s.t0) * 1e3, 4)}
             for s in sorted(mine, key=lambda s: s.t0)]
+    t1 = max(s.t1 for s in mine)
+    bf = next((s for s in mine if s.name == "serve.batch_formation"), None)
     if bf is not None:
-        t0 = bf.t1
-        for d in spans:
-            if d.name == "serve.dispatch" and abs(d.t0 - t0) < 1e-9:
-                rows.append({"span": d.name,
-                             "ms": round((d.t1 - d.t0) * 1e3, 4),
-                             "batch": d.args.get("batch")})
-                break
-        for s in spans:
-            if s.name.startswith("layer:") and s.t0 >= t0 - 1e-9:
-                dispatch = next((d for d in spans
-                                 if d.name == "serve.dispatch"
-                                 and d.t0 <= s.t0 and s.t1 <= d.t1 + 1e-9),
-                                None)
-                if dispatch is not None and abs(dispatch.t0 - t0) < 1e-6:
-                    rows.append({"span": s.name,
-                                 "ms": round((s.t1 - s.t0) * 1e3, 4),
-                                 "executor": s.args.get("executor", "?")})
+        rows += [{"span": s.name, "ms": round((s.t1 - s.t0) * 1e3, 4)}
+                 for s in spans
+                 if s.name.startswith("serve.") and "rid" not in s.args
+                 and s.name != "serve.batch"
+                 and bf.t0 <= s.t0 and s.t1 <= t1]
     return rows
 
 
@@ -179,8 +167,8 @@ def main(argv=None) -> None:
     p50_en = float(np.percentile(lat_en, 50)) * 1e3
     overhead = (p50_en - p50_dis) / p50_dis * 100
     max_resid = max((r["residual_pct"] for r in decomp), default=1e9)
-    n_layer_spans = sum(1 for r in span_table
-                        if r["span"].startswith("layer:"))
+    n_phase_spans = sum(1 for r in span_table
+                        if r["span"] in ("serve.eager", "serve.await"))
     valid = (isinstance(chrome.get("traceEvents"), list)
              and len(chrome["traceEvents"]) > 0
              and all("ph" in e for e in chrome["traceEvents"]))
@@ -206,7 +194,7 @@ def main(argv=None) -> None:
             "overhead_lt_10pct": overhead < 10.0,
             "decomposition_residual_lt_1pct": max_resid < 1.0,
             "valid_chrome_trace": bool(valid),
-            "layer_spans_present": n_layer_spans > 0,
+            "phase_spans_present": n_phase_spans > 0,
         },
     }
     with open(args.out, "w") as f:

@@ -58,9 +58,12 @@ import numpy as np
 from repro.core import partition as _partition
 from repro.core import plan as _plan
 from repro.core import registry
+from repro.kernels import runtime as _kernel_runtime
 from repro.obs import trace as _obs_trace
 
 ARTIFACT_FORMAT = "repro.network_plan"
+#: node attrs passed to a plan's apply (the rest are in the plan)
+_EPILOGUE_ATTRS = ("activation", "inner_activation")
 # v2: conv layer metas gained the fft/winograd_f63 algorithms plus N-way
 # autotune evidence (winner/winner_tile and per-contender timings); v1
 # readers would mis-plan those layers, so the version gates them out.
@@ -664,6 +667,26 @@ def _node_from_json(d: dict) -> LayerIR:
                    attrs=attrs, block=d.get("block"))
 
 
+def _static_key(obj) -> Any:
+    """A hashable key of a bound plan's static content: arrays by shape
+    and dtype, its build time left out."""
+    if isinstance(obj, (jax.Array, np.ndarray)):
+        return (obj.shape, str(obj.dtype))
+    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+        return (type(obj).__name__,) + tuple(
+            (f.name, _static_key(getattr(obj, f.name)))
+            for f in dataclasses.fields(obj) if f.name != "build_time_s")
+    if isinstance(obj, (list, tuple)):
+        return tuple(map(_static_key, obj))
+    if isinstance(obj, dict):
+        return tuple(sorted((k, _static_key(v)) for k, v in obj.items()))
+    try:
+        hash(obj)
+    except TypeError:
+        return repr(obj)
+    return obj
+
+
 def _plan_weight_arrays(p) -> list[jax.Array]:
     """The execution-domain weight arrays a bound LayerPlan holds (what
     plan build materializes; benchmarks block_until_ready on these)."""
@@ -835,6 +858,38 @@ class NetworkPlan:
         return env[self.graph[-1].id]
 
     def _eval_node(self, node, a, v, env, c):
+        """One node under its named scope "<op>:<id>", which names the
+        node's ops in the HLO and its Pallas kernels (kernels.runtime)."""
+        with _kernel_runtime.node_scope(node.op, node.id,
+                                        self._kernel_labels().get(node.id)):
+            return self._eval_op(node, a, v, env, c)
+
+    def _kernel_labels(self) -> dict[str, str]:
+        """Node id -> the label its Pallas kernels are named by: the ids,
+        joined by "-", of every node with the same op, input shape, plan
+        (arrays by shape and dtype), epilogue and constants -- nodes whose
+        kernels are one computation, which JAX then traces and lowers once,
+        as it does unnamed. Kept until a bound plan changes."""
+        token = tuple(map(id, self.plans.values()))
+        cached = self.__dict__.get("_labels")
+        if cached is None or cached[0] != token:
+            shapes = infer_shapes(self.graph, self.input_shape)
+            alike: dict[Any, list[str]] = {}
+            for node in self.graph:
+                if node.id not in self.plans:
+                    continue
+                key = (node.op, shapes[node.inputs[0]],
+                       _static_key(self.plans[node.id]),
+                       tuple(node.attrs.get(k) for k in _EPILOGUE_ATTRS),
+                       tuple(sorted(k[len(node.id):] for k in self.consts
+                                    if k.startswith(node.id + "."))))
+                alike.setdefault(key, []).append(node.id)
+            cached = (token, {nid: "-".join(ids) for ids in alike.values()
+                              for nid in ids})
+            self.__dict__["_labels"] = cached
+        return cached[1]
+
+    def _eval_op(self, node, a, v, env, c):
             if node.op == "conv2d":
                 y = self.plans[node.id].apply(
                     v, bias=c.get(f"{node.id}.b"),

@@ -100,7 +100,7 @@ def _depthwise_kernel(x_ref, u_ref, bias_ref, scale_ref, o_ref, y_ref, *,
 
 
 def _depthwise_call(xp, u, bias, scale, *, ct_h, ct_w, stride, bh, bw,
-                    block_c, activation, interpret):
+                    block_c, activation, interpret, name):
     """pallas_call shared by the stride-1 and stride-2 depthwise kernels.
     `u` is (phases*P, Cp, mult); bias/scale are (1, Cp*mult) rows in the
     o = c*mult + j order. Returns (N, Ho, Wo, Cp*mult)."""
@@ -145,6 +145,7 @@ def _depthwise_call(xp, u, bias, scale, *, ct_h, ct_w, stride, bh, bw,
         scratch_shapes=[pltpu.VMEM((th * tw, bh * bw, block_c),
                                    jnp.float32)],
         interpret=interpret,
+        name=name,
     )(xp, u.transpose(0, 2, 1), bias, scale)
     if mult == 1:
         return y[0]
@@ -152,7 +153,8 @@ def _depthwise_call(xp, u, bias, scale, *, ct_h, ct_w, stride, bh, bw,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "ct_h", "ct_w", "bh", "bw", "block_c", "activation", "interpret"))
+    "ct_h", "ct_w", "bh", "bw", "block_c", "activation", "interpret",
+    "name"))
 def depthwise_streamed(
     xp: jax.Array,           # (N, Hp, Wp, Cp) halo-padded NHWC input
     u: jax.Array,            # (P, Cp, mult) Winograd-domain depthwise taps
@@ -166,6 +168,7 @@ def depthwise_streamed(
     block_c: int = 128,
     activation: str = "none",
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Halo-streaming depthwise transform+Hadamard+inverse+epilogue.
 
@@ -178,11 +181,13 @@ def depthwise_streamed(
     """
     return _depthwise_call(xp, u, bias, scale, ct_h=ct_h, ct_w=ct_w,
                            stride=1, bh=bh, bw=bw, block_c=block_c,
-                           activation=activation, interpret=interpret)
+                           activation=activation, interpret=interpret,
+                           name=name)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "ct_h", "ct_w", "bh", "bw", "block_c", "activation", "interpret"))
+    "ct_h", "ct_w", "bh", "bw", "block_c", "activation", "interpret",
+    "name"))
 def depthwise_strided_streamed(
     xp: jax.Array,           # (N, Hp, Wp, Cp) halo-padded full-res input
     u: jax.Array,            # (4P, Cp) phase-major Winograd-domain taps
@@ -196,6 +201,7 @@ def depthwise_strided_streamed(
     block_c: int = 128,
     activation: str = "none",
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Stride-2 streamed depthwise conv via transform-domain phase
     decomposition: the MobileNet reduction-block depthwise layer as one
@@ -209,7 +215,7 @@ def depthwise_strided_streamed(
     return _depthwise_call(xp, u[:, :, None], bias, scale, ct_h=ct_h,
                            ct_w=ct_w, stride=2, bh=bh, bw=bw,
                            block_c=block_c, activation=activation,
-                           interpret=interpret)
+                           interpret=interpret, name=name)
 
 
 # ---------------------------------------------------------------------------
@@ -265,7 +271,7 @@ def _separable_kernel(x_ref, udw_ref, upw_ref, bdw_ref, bpw_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "ct_h", "ct_w", "bh", "bw", "block_c", "block_m", "inner_activation",
-    "activation", "interpret"))
+    "activation", "interpret", "name"))
 def separable_streamed(
     xp: jax.Array,            # (N, Hp, Wp, Cp) halo-padded NHWC input
     u_dw: jax.Array,          # (P, Cp) Winograd-domain depthwise taps
@@ -282,6 +288,7 @@ def separable_streamed(
     inner_activation: str = "none",
     activation: str = "none",
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Fused separable block over the halo-padded input: depthwise Winograd
     + bias/activation + pointwise 1x1 + bias/activation in one kernel; the
@@ -336,4 +343,5 @@ def separable_streamed(
                         # per-point depthwise products
                         pltpu.VMEM((p, bh * bw, block_c), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(xp, u_dw, u_pw, bias_dw, bias_pw)

@@ -52,12 +52,13 @@ def _matmul_kernel(a_ref, b_ref, bias_ref, scale_ref, o_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "activation",
-                                             "interpret"))
+                                             "interpret", "name"))
 def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
            bk: int = 128, bias: jax.Array | None = None,
            scale: jax.Array | None = None,
            activation: str = "none",
-           interpret: bool | None = None) -> jax.Array:
+           interpret: bool | None = None,
+           name: str | None = None) -> jax.Array:
     """C[M, N] = act(scale * (A[M, K] @ B[K, N]) + bias), fp32 accumulation.
 
     M, K, N must be multiples of the block sizes (ops.py pads). `bias` is a
@@ -94,4 +95,5 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(a, b, bias, scale)

@@ -32,6 +32,7 @@ from repro.kernels import matmul as _k_matmul
 from repro.kernels import winograd as _k_winograd
 from repro.kernels.runtime import default_interpret as _default_interpret
 from repro.kernels.runtime import epilogue_jnp as _epilogue_jnp
+from repro.kernels.runtime import kernel_name as _kernel_name
 from repro.kernels.runtime import pick_block as _block
 from repro.kernels.runtime import resolve_interpret as _resolve_interpret
 
@@ -90,7 +91,8 @@ def winograd_conv2d_planned(
     y = _k_winograd.winograd_streamed(
         xp, u, _pad_bias(bias, stream.m_pad), scale, ct_h=ct_h, ct_w=ct_w,
         bh=stream.bh, bw=stream.bw, block_c=stream.block_c,
-        block_m=stream.block_m, activation=activation, interpret=interpret)
+        block_m=stream.block_m, activation=activation, interpret=interpret,
+        name=_kernel_name("winograd_streamed"))
     return y[:, :geometry.out_h, :geometry.out_w, :c_out]
 
 
@@ -159,7 +161,8 @@ def winograd_strided_conv2d_planned(
     y = _k_winograd.winograd_strided_streamed(
         xp, u, _pad_bias(bias, stream.m_pad), scale, ct_h=ct_h, ct_w=ct_w,
         bh=stream.bh, bw=stream.bw, block_c=stream.block_c,
-        block_m=stream.block_m, activation=activation, interpret=interpret)
+        block_m=stream.block_m, activation=activation, interpret=interpret,
+        name=_kernel_name("winograd_strided_streamed"))
     return y[:, :geometry.out_h, :geometry.out_w, :c_out]
 
 
@@ -189,7 +192,8 @@ def depthwise_strided_conv2d_planned(
     y = _k_depthwise.depthwise_strided_streamed(
         xp, u, _pad_bias(bias, stream.c_pad), scale, ct_h=ct_h, ct_w=ct_w,
         bh=stream.bh, bw=stream.bw, block_c=stream.block_c,
-        activation=activation, interpret=interpret)
+        activation=activation, interpret=interpret,
+        name=_kernel_name("depthwise_strided_streamed"))
     return y[:, :geometry.out_h, :geometry.out_w, :c_out]
 
 
@@ -245,7 +249,8 @@ def winograd_conv2d_planned_materialized(
 
     y = _k_winograd.winograd_fused(
         tiles, u, ct_h=ct_h, ct_w=ct_w, block_r=br, block_c=bc, block_m=bm,
-        interpret=interpret)                             # (Rp, mh, mw, Mp)
+        interpret=interpret,
+        name=_kernel_name("winograd_fused"))      # (Rp, mh, mw, Mp)
     y = y[:r_tot, :, :, :c_out].reshape(n, nh, nw, ct_h.m, ct_w.m, c_out)
     y = y.transpose(0, 1, 3, 2, 4, 5).reshape(
         n, nh * ct_h.m, nw * ct_w.m, c_out)
@@ -286,7 +291,8 @@ def depthwise_conv2d_planned(
     y = _k_depthwise.depthwise_streamed(
         xp, u, _pad_bias(bias, stream.c_pad * mult), scale, ct_h=ct_h,
         ct_w=ct_w, bh=stream.bh, bw=stream.bw, block_c=stream.block_c,
-        activation=activation, interpret=interpret)
+        activation=activation, interpret=interpret,
+        name=_kernel_name("depthwise_streamed"))
     return y[:, :geometry.out_h, :geometry.out_w, :c_out]
 
 
@@ -321,7 +327,8 @@ def separable_conv2d_planned(
         _pad_bias(bias_pw, stream.m_pad), ct_h=ct_h, ct_w=ct_w,
         bh=stream.bh, bw=stream.bw, block_c=stream.block_c,
         block_m=stream.block_m, inner_activation=inner_activation,
-        activation=activation, interpret=interpret)
+        activation=activation, interpret=interpret,
+        name=_kernel_name("separable_streamed"))
     return y[:, :geometry.out_h, :geometry.out_w, :c_out]
 
 
@@ -371,7 +378,8 @@ def im2col_conv2d_planned(
     a = _pad_axis(_pad_axis(a, 0, _round_up(mm, bm_)), 1, _round_up(kk, bk_))
     y = _k_matmul.matmul(a, b, bm=bm_, bn=bn_, bk=bk_,
                          bias=_pad_bias(bias, b.shape[1]), scale=scale,
-                         activation=activation, interpret=interpret)
+                         activation=activation, interpret=interpret,
+                         name=_kernel_name("matmul"))
     return y[:mm, :c_out].reshape(n, oh, ow, c_out).astype(x.dtype)
 
 

@@ -9,10 +9,22 @@ interpret mode on any other (the CPU test runs).
 `apply_activation` is the epilogue vocabulary shared by the Winograd and GEMM
 kernels (bias add + none/relu/relu6/gelu) and by the pure-JAX executors, so
 every conv backend exposes the same fused-epilogue contract.
+
+`node_scope` and `kernel_name` carry a network node's identity to the device:
+`NetworkPlan` evaluates each node inside `node_scope(op, id)`, which opens the
+`jax.named_scope` "<op>:<id>" (so every HLO op of the node carries it in its
+`op_name` metadata), and a Pallas kernel called inside it is named
+"<family>__<op>__<label>" (the HLO instruction, and so the op in a device
+trace, takes that name plus XLA's ".<n>" suffix). The label is the node id,
+or the ids of every node whose kernels are alike, joined by "-"
+(`NetworkPlan._kernel_labels`): JAX traces and lowers a kernel once per
+name, and identical kernels take equal time.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 
 import jax
@@ -40,6 +52,32 @@ def default_interpret() -> bool:
 
 def resolve_interpret(interpret: bool | None) -> bool:
     return default_interpret() if interpret is None else bool(interpret)
+
+
+#: the (op, kernel label) of the node being evaluated on this thread
+_NODE: contextvars.ContextVar = contextvars.ContextVar("repro_node",
+                                                       default=None)
+
+
+@contextlib.contextmanager
+def node_scope(op: str, node_id: str, label: str | None = None):
+    """Evaluate one network node under the named scope "<op>:<id>"; its
+    kernels are named by `label`, by default the node id."""
+    token = _NODE.set((op, label or node_id))
+    try:
+        with jax.named_scope(f"{op}:{node_id}"):
+            yield
+    finally:
+        _NODE.reset(token)
+
+
+def kernel_name(family: str) -> str:
+    """The `pallas_call` name of a `family` kernel: "<family>__<op>__<label>"
+    inside a node scope, the family alone outside one. The eager and the
+    jitted walk of a network name a kernel alike, so the jitted trace
+    reuses the kernel traces of the eager warm-up."""
+    node = _NODE.get()
+    return family if node is None else f"{family}__{node[0]}__{node[1]}"
 
 
 def apply_activation(y: jax.Array, activation: str) -> jax.Array:

@@ -232,7 +232,7 @@ def _streamed_kernel(x_ref, u_ref, bias_ref, scale_ref, o_ref, acc_ref, v_ref,
 
 
 def _streamed_call(xp, u, bias, scale, *, ct_h, ct_w, stride, bh, bw,
-                   block_c, block_m, activation, interpret):
+                   block_c, block_m, activation, interpret, name):
     """pallas_call shared by the stride-1 and stride-2 streamed kernels."""
     interpret = resolve_interpret(interpret)
     n, hp, wp, c = xp.shape
@@ -289,12 +289,13 @@ def _streamed_call(xp, u, bias, scale, *, ct_h, ct_w, stride, bh, bw,
                         # (M, C) sweep.
                         pltpu.VMEM((n_c, pp, bh * bw, block_c), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(xp, u, bias, scale)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "ct_h", "ct_w", "bh", "bw", "block_c", "block_m", "activation",
-    "interpret"))
+    "interpret", "name"))
 def winograd_streamed(
     xp: jax.Array,           # (N, Hp, Wp, Cp) halo-padded NHWC input
     u: jax.Array,            # (P, Cp, Mp) Winograd-domain filter (P = th*tw)
@@ -309,6 +310,7 @@ def winograd_streamed(
     block_m: int = 128,
     activation: str = "none",
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Halo-streaming transform+GEMM+inverse+epilogue over the padded input.
 
@@ -319,12 +321,13 @@ def winograd_streamed(
     """
     return _streamed_call(xp, u, bias, scale, ct_h=ct_h, ct_w=ct_w, stride=1,
                           bh=bh, bw=bw, block_c=block_c, block_m=block_m,
-                          activation=activation, interpret=interpret)
+                          activation=activation, interpret=interpret,
+                          name=name)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "ct_h", "ct_w", "bh", "bw", "block_c", "block_m", "activation",
-    "interpret"))
+    "interpret", "name"))
 def winograd_strided_streamed(
     xp: jax.Array,           # (N, Hp, Wp, Cp) halo-padded full-res input
     u: jax.Array,            # (4P, Cp, Mp) phase-major Winograd-domain filter
@@ -339,6 +342,7 @@ def winograd_strided_streamed(
     block_m: int = 128,
     activation: str = "none",
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Stride-2 halo-streaming Winograd conv via transform-domain phase
     decomposition: four phase input-transforms + GEMM banks per strip, one
@@ -352,7 +356,8 @@ def winograd_strided_streamed(
     """
     return _streamed_call(xp, u, bias, scale, ct_h=ct_h, ct_w=ct_w, stride=2,
                           bh=bh, bw=bw, block_c=block_c, block_m=block_m,
-                          activation=activation, interpret=interpret)
+                          activation=activation, interpret=interpret,
+                          name=name)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +394,8 @@ def _winograd_kernel(bt_h_ref, bt_w_ref, at_h_ref, at_w_ref, x_ref, u_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("ct_h", "ct_w", "block_r",
-                                             "block_c", "block_m", "interpret"))
+                                             "block_c", "block_m", "interpret",
+                                             "name"))
 def winograd_fused(
     tiles: jax.Array,        # (R, th, tw, C) pre-extracted input tiles
     u: jax.Array,            # (P, C, M) Winograd-domain filter (P = th*tw)
@@ -400,6 +406,7 @@ def winograd_fused(
     block_c: int = 128,
     block_m: int = 128,
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """Fused transform+GEMM+inverse over pre-extracted tiles.
 
@@ -436,4 +443,5 @@ def winograd_fused(
         out_shape=jax.ShapeDtypeStruct((r_, ct_h.m, ct_w.m, m), tiles.dtype),
         scratch_shapes=[pltpu.VMEM((p, block_r, block_m), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(bt_h, bt_w, at_h, at_w, tiles, u)

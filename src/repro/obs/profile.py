@@ -1,53 +1,41 @@
-"""The Profiler: wires tracing + metrics through the serving hot path.
+"""The Profiler: the per-request view of the serving hot path.
 
 `enable()` installs the global tracer (repro.obs.trace) and a `Profiler`
 that `runtime/serve.py` consults via `active()` -- one global read per
-batch, None when profiling is off, so the disabled serve path records
-nothing (tested). compile() pass phases and plan-cache / autotune-race
-events in core/plan.py and core/compile.py report through the same
-global tracer directly, so enabling the profiler lights up the whole
-stack: plan -> compile -> serve in one trace.
+batch, None when profiling is off. compile() pass phases and plan-cache /
+autotune-race events in core/plan.py and core/compile.py report through
+the same global tracer directly, so enabling the profiler lights up the
+whole stack: plan -> compile -> serve in one trace.
 
-Per-request decomposition (`serve_batch`): the server hands over the
-batch's boundary timestamps -- submit (per ticket), batch selection,
-dispatch start/end, finish (per ticket) -- plus the per-layer wall times
-that `NetworkPlan.apply(layer_hook=)` measured on the eager supervised
-path. The profiler turns those into spans:
+The server's own phase spans (`serve.batch` and its children, see
+runtime/serve.py) are live `trace.span`s: they record here while the
+tracer is enabled and land in a JAX profiler trace while one records.
+What only the profiler adds is the per-request decomposition
+(`serve_batch`): the server hands over the batch's boundary stamps --
+submit (per ticket), batch selection, dispatch start/end, finish (per
+ticket) -- and the profiler turns them into spans per request:
 
-    serve.queue_wait        submit -> batch selection        (per request)
-    serve.batch_formation   selection -> dispatch start      (per request)
-    serve.dispatch          dispatch start -> end            (per batch)
-      layer:<node_id>         sequential children, one per planned layer,
-                              tagged with the executing plan's executor
-    serve.respond           dispatch end -> ticket finish    (per request)
+    serve.queue_wait        submit -> batch selection
+    serve.batch_formation   selection -> dispatch start
+    serve.respond           dispatch end -> ticket finish
 
-Those four intervals tile [submit, finish] exactly (same perf_counter
-clock, shared boundaries), so per request they sum to the measured
-latency -- the acceptance contract tests/test_obs.py asserts. Layer spans
-exist only when the eager supervised path ran; the jitted (and sharded)
-happy path cannot observe layer boundaries inside the fused computation,
-so its dispatch span stands alone (`jitted=True`).
+With the batch's dispatch interval (its live `serve.h2d` through
+`serve.await` spans) between them, they tile [submit, finish] (same
+perf_counter clock, shared stamps), so per request they sum to the
+measured latency -- the contract tests/test_obs.py asserts. These spans
+are recorder-only: they are made after the batch, so a profiler trace
+does not hold them.
 
-Latency/queue-wait/dispatch histograms go to the default metrics
-registry under `serve.*`.
+Latency and queue-wait histograms go to the default metrics registry
+under `serve.*`.
 """
 
 from __future__ import annotations
-
-from typing import Any
 
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 __all__ = ["Profiler", "enable", "disable", "active", "is_enabled"]
-
-
-def _executor_of(plan: Any) -> str:
-    """Best-effort executor label for a bound layer plan."""
-    try:
-        return str(plan.describe().get("executor", type(plan).__name__))
-    except Exception:
-        return type(plan).__name__
 
 
 class Profiler:
@@ -60,30 +48,13 @@ class Profiler:
 
     # ---- the serve hot path ----------------------------------------------
 
-    def serve_batch(self, *, bucket: int, batch: list, net: Any,
-                    t_select: float, t0: float, t1: float,
-                    layer_times: dict[str, float],
-                    jitted: bool, sharded: bool = False) -> None:
-        """Record one dispatched batch. `batch` is the ticket list
-        (rid / submitted_at / finished_at), `t_select` the batch-selection
-        stamp from the scheduler loop, [t0, t1] the dispatch interval,
-        `layer_times` the per-node wall seconds from layer_hook (empty on
-        the jitted path)."""
+    def serve_batch(self, *, bucket: int, batch: list, t_select: float,
+                    t0: float, t1: float) -> None:
+        """Record one dispatched batch's requests. `batch` is the ticket
+        list (rid / submitted_at / finished_at), `t_select` the
+        batch-selection stamp from the scheduler loop, [t0, t1] the
+        dispatch interval."""
         tr, reg = self.tracer, self.registry
-        tr.add_span("serve.dispatch", t0, t1, bucket=bucket,
-                    batch=len(batch), jitted=jitted, sharded=sharded)
-        reg.observe("serve.dispatch_s", t1 - t0)
-        # Layer children: apply() runs nodes sequentially and the hook
-        # fires with each node's own wall time, so laying the durations
-        # end-to-end from t0 reconstructs starts to within the (un-hooked)
-        # pad/pool/add glue between planned layers.
-        cursor = t0
-        for nid, dt in layer_times.items():
-            plan = net.plans.get(nid) if net is not None else None
-            tr.add_span(f"layer:{nid}", cursor, cursor + dt,
-                        executor=_executor_of(plan))
-            reg.observe("serve.layer_s", dt)
-            cursor += dt
         for t in batch:
             rid = t.rid
             tr.add_span("serve.queue_wait", t.submitted_at, t_select,

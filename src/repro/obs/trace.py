@@ -1,4 +1,11 @@
-"""Thread-safe span tracing with chrome://tracing export. Stdlib only.
+"""Thread-safe span tracing with chrome://tracing export.
+
+`span()` feeds two sinks: the in-memory `Tracer` below, when one is enabled,
+and a `jax.profiler.TraceAnnotation`, whenever a JAX profiler session is
+recording (`jax.profiler.start_trace`), so program spans land in the
+profiler's trace on the device trace's clock, next to the runtime's events.
+The module imports only the standard library; `jax.profiler` is imported on
+the first `span()` call.
 
 A `Tracer` records completed spans -- (name, t0, t1, thread, depth, args)
 over `time.perf_counter()` timestamps -- into a bounded ring buffer
@@ -15,10 +22,11 @@ sources:
   decisions).
 
 The module-level API (`enable()` / `disable()` / `span()` / ...) routes
-through one global tracer. Disabled -- the default -- every hook is a
-single `is None` check and `span()` returns a shared no-op context
-manager, so instrumented hot paths pay (provably, see
-tests/test_obs.py::test_serve_disabled_emits_zero_spans) nothing.
+through one global tracer. With no tracer and no profiler session -- the
+default -- `span()` is an `is None` check plus the profiler's own
+is-recording check, and returns a shared no-op context manager: no span
+object, no args formatted (tests/test_obs.py::
+test_serve_disabled_emits_zero_spans).
 
 `export_chrome()` emits the chrome://tracing / Perfetto "traceEvents"
 JSON: "X" complete events (ts/dur in microseconds, rebased to the first
@@ -36,7 +44,8 @@ from collections import deque
 from typing import Any, Iterator
 
 __all__ = ["Span", "Tracer", "enable", "disable", "get", "is_enabled",
-           "span", "add_span", "instant", "export_chrome", "NULL_SPAN"]
+           "profiling", "span", "add_span", "instant", "export_chrome",
+           "NULL_SPAN"]
 
 DEFAULT_CAPACITY = 65536
 
@@ -67,33 +76,45 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager recording one nested span on exit."""
+    """Context manager for one nested span: recorded into `tracer` on exit
+    (when not None) and mirrored by `annotation`, a started-on-enter
+    profiler annotation (when not None)."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    def __init__(self, tracer: "Tracer | None", name: str, args: dict,
+                 annotation=None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = annotation
 
     def __enter__(self) -> "_SpanCtx":
-        self._depth = self._tracer._push()
-        self._t0 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._depth = self._tracer._push()
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.perf_counter()
-        self._tracer._pop()
-        if exc_type is not None:
-            self._args = dict(self._args, error=repr(exc))
-        self._tracer._record(Span(self._name, self._t0, t1,
-                                  threading.get_ident(), self._depth,
-                                  self._args))
+        tr = self._tracer
+        if tr is not None:
+            t1 = time.perf_counter()
+            tr._pop()
+            if exc_type is not None:
+                self._args = dict(self._args, error=repr(exc))
+            tr._record(Span(self._name, self._t0, t1, threading.get_ident(),
+                            self._depth, self._args))
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **args: Any) -> None:
         """Attach args discovered mid-span (e.g. the autotune winner)."""
         self._args = dict(self._args, **args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
 
 class _NullSpan:
@@ -223,6 +244,16 @@ class Tracer:
 # ---------------------------------------------------------------------------
 
 _TRACER: Tracer | None = None
+#: jax.profiler.TraceAnnotation, imported on the first `profiling()` call
+_ANNOTATION: Any = None
+
+
+def profiling() -> bool:
+    """Whether a JAX profiler session is recording host annotations."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation as _ANNOTATION
+    return _ANNOTATION.is_enabled()
 
 
 def enable(capacity: int = DEFAULT_CAPACITY) -> Tracer:
@@ -247,11 +278,14 @@ def is_enabled() -> bool:
 
 
 def span(name: str, **args: Any):
-    """`with trace.span("compile.place"): ...` -- no-op when disabled."""
+    """`with trace.span("compile.place"): ...`: recorded by the global
+    tracer and annotated into a recording profiler session; the shared
+    no-op when neither is on."""
     t = _TRACER
-    if t is None:
+    ann = _ANNOTATION(name, **args) if profiling() else None
+    if t is None and ann is None:
         return NULL_SPAN
-    return t.span(name, **args)
+    return _SpanCtx(t, name, args, ann)
 
 
 def add_span(name: str, t0: float, t1: float, **args: Any) -> None:

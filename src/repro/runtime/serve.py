@@ -69,6 +69,7 @@ from repro.core import compile as _compile
 from repro.core import plan as _plan
 from repro.obs import metrics as _obs_metrics
 from repro.obs import profile as _obs_profile
+from repro.obs import trace as _trace
 from repro.runtime.fault import Backoff, StepTimer
 
 
@@ -206,9 +207,9 @@ _STAT_COUNTERS = (
     # outputs outside budget never keep serving).
     "precision_promotions",
     # jitted-happy-path accounting: batches served by the jitted apply,
-    # and buckets that fell back to the eager supervised path on their
-    # first fault.
-    "jit_dispatches", "jit_fallbacks",
+    # buckets that fell back to the eager supervised path on their first
+    # fault, and jitted callables built (each compiles on its first call).
+    "jit_dispatches", "jit_fallbacks", "jit_builds",
     # continuous re-placement: probation re-probes run, and evicted layers
     # promoted back onto their original algorithm.
     "probation_reprobes", "probation_promotions",
@@ -641,91 +642,108 @@ class Server:
         return self.buckets[-1]
 
     def _loop(self) -> None:
+        """The scheduler thread. Each batch is one `serve.batch` span whose
+        children tile it: serve.select, serve.assemble, serve.h2d, then
+        serve.enqueue + serve.await (jitted) or serve.eager (supervised),
+        serve.d2h, serve.respond. Waiting for work is `serve.idle`."""
         cfg = self.config
         while True:
             with self._cv:
-                while not self._queue and not self._stop:
-                    self._cv.wait(0.1)
+                with _trace.span("serve.idle"):
+                    while not self._queue and not self._stop:
+                        self._cv.wait(0.1)
+                    # dynamic batch formation: let a burst coalesce into a
+                    # fuller bucket instead of dispatching singles.
+                    if (0 < len(self._queue) < self.buckets[-1]
+                            and not self._stop and cfg.batch_wait_s > 0):
+                        self._cv.wait(cfg.batch_wait_s)
                 if self._stop and (not self._queue or not self._draining):
                     return
-                # dynamic batch formation: let a burst coalesce into a
-                # fuller bucket instead of dispatching singles.
-                if (0 < len(self._queue) < self.buckets[-1]
-                        and not self._stop and cfg.batch_wait_s > 0):
-                    self._cv.wait(cfg.batch_wait_s)
-                now = time.perf_counter()
-                live = []
-                for t in self._queue:
-                    if t.done():                    # client-side cancel
-                        self.stats.inc("cancelled")
-                    elif t.deadline is not None and t.deadline <= now:
-                        # timeout-cancel while queued: never executed
-                        t._finish("timeout", error=TimeoutError(
-                            f"request {t.rid} deadline expired "
-                            f"{now - t.deadline:.3f}s before dispatch"))
-                        self.stats.inc("timed_out")
-                    else:
-                        live.append(t)
-                # EDF: earliest deadline first, FIFO among deadline-less.
-                live.sort(key=lambda t: (
-                    t.deadline if t.deadline is not None else math.inf,
-                    t.rid))
-                take = min(len(live), self.buckets[-1])
-                batch, self._queue = live[:take], live[take:]
-                # queue-wait / batch-formation boundary for the profiler:
-                # everything before this stamp is time spent queued,
-                # everything until dispatch start is batch assembly.
-                t_select = time.perf_counter()
-            if batch:
-                self._run_batch(batch, t_select)
+            with _trace.span("serve.batch") as span:
+                with _trace.span("serve.select"):
+                    batch, t_select = self._select()
+                if batch:
+                    self._run_batch(batch, t_select, span)
 
-    def _run_batch(self, batch: list[Ticket],
-                   t_select: float | None = None) -> None:
-        prof = _obs_profile.active()   # ONE global read; None = disabled
-        b = self._bucket_for(len(batch))
-        X = np.zeros((b,) + self.example_shape, self.np_dtype)
-        for i, t in enumerate(batch):
-            X[i] = t.x
-        t0 = time.perf_counter()
-        fails_before = self.stats.executor_failures
-        jit_before = self.stats.jit_dispatches
+    def _select(self) -> tuple[list[Ticket], float]:
+        """The queue scan and EDF pick: requests cancelled or expired while
+        queued leave, and up to the largest bucket of the rest, earliest
+        deadline first, form the batch. Returns it with the selection
+        stamp."""
+        with self._cv:
+            now = time.perf_counter()
+            live = []
+            for t in self._queue:
+                if t.done():                        # client-side cancel
+                    self.stats.inc("cancelled")
+                elif t.deadline is not None and t.deadline <= now:
+                    # timeout-cancel while queued: never executed
+                    t._finish("timeout", error=TimeoutError(
+                        f"request {t.rid} deadline expired "
+                        f"{now - t.deadline:.3f}s before dispatch"))
+                    self.stats.inc("timed_out")
+                else:
+                    live.append(t)
+            # EDF: earliest deadline first, FIFO among deadline-less.
+            live.sort(key=lambda t: (
+                t.deadline if t.deadline is not None else math.inf, t.rid))
+            take = min(len(live), self.buckets[-1])
+            batch, self._queue = live[:take], live[take:]
+            # queue-wait / batch-formation boundary for the profiler:
+            # everything before this stamp is time spent queued,
+            # everything until dispatch start is batch assembly.
+            return batch, time.perf_counter()
+
+    def _run_batch(self, batch: list[Ticket], t_select: float,
+                   span) -> None:
+        with _trace.span("serve.assemble"):
+            prof = _obs_profile.active()   # ONE global read; None = off
+            b = self._bucket_for(len(batch))
+            if span is not _trace.NULL_SPAN:
+                span.set(bucket=b, rows=len(batch), padded=b)
+            X = np.zeros((b,) + self.example_shape, self.np_dtype)
+            for i, t in enumerate(batch):
+                X[i] = t.x
+            fails_before = self.stats.executor_failures
+            t0 = time.perf_counter()
         try:
-            y, layer_times = self._dispatch(b, jnp.asarray(X))
+            with _trace.span("serve.h2d"):
+                X = jnp.asarray(X)
+            y, layer_times = self._dispatch(b, X)
         except Exception as e:
             # ladder exhausted: answer every ticket with the error --
             # failed, but never silently dropped.
-            for t in batch:
-                if t._finish("error", error=e):
-                    self.stats.inc("failed")
-            self.stats.inc("batches")
-            if prof is not None:
-                prof.serve_batch_error(bucket=b, batch=batch, error=e)
+            with _trace.span("serve.respond"):
+                for t in batch:
+                    if t._finish("error", error=e):
+                        self.stats.inc("failed")
+                self.stats.inc("batches")
+                if prof is not None:
+                    prof.serve_batch_error(bucket=b, batch=batch, error=e)
             return
         t1 = time.perf_counter()
-        dt = t1 - t0
-        a = self.config.ewma_alpha
-        self._service_ewma = (dt if self._service_ewma is None
-                              else (1 - a) * self._service_ewma + a * dt)
-        self._observe_stragglers(b, dt, layer_times)
-        y = np.asarray(y)
-        now = time.perf_counter()
-        for i, t in enumerate(batch):
-            if t.deadline is not None and t.deadline < now:
-                t.deadline_missed = True
-                self.stats.inc("deadline_missed")
-            if t._finish("ok", value=y[i]):
-                self.stats.inc("completed")
-        self.stats.inc("batches")
-        self.stats.bump_bucket(b)
-        if prof is not None:
-            prof.serve_batch(
-                bucket=b, batch=batch, net=self.nets.get(b),
-                t_select=t_select if t_select is not None else t0,
-                t0=t0, t1=t1, layer_times=layer_times,
-                jitted=self.stats.jit_dispatches > jit_before,
-                sharded=b in self.sharded_nets)
-        if self.stats.executor_failures == fails_before:
-            self._note_clean_batch()
+        with _trace.span("serve.d2h"):
+            y = np.asarray(y)
+        with _trace.span("serve.respond"):
+            dt = t1 - t0
+            a = self.config.ewma_alpha
+            self._service_ewma = (dt if self._service_ewma is None
+                                  else (1 - a) * self._service_ewma + a * dt)
+            self._observe_stragglers(b, dt, layer_times)
+            now = time.perf_counter()
+            for i, t in enumerate(batch):
+                if t.deadline is not None and t.deadline < now:
+                    t.deadline_missed = True
+                    self.stats.inc("deadline_missed")
+                if t._finish("ok", value=y[i]):
+                    self.stats.inc("completed")
+            self.stats.inc("batches")
+            self.stats.bump_bucket(b)
+            if prof is not None:
+                prof.serve_batch(bucket=b, batch=batch, t_select=t_select,
+                                 t0=t0, t1=t1)
+            if self.stats.executor_failures == fails_before:
+                self._note_clean_batch()
 
     # ---- dispatch: the jitted happy path ---------------------------------
 
@@ -740,9 +758,11 @@ class Server:
         token = (id(net), *map(id, net.plans.values()))
         cached = self._jit.get(bucket)
         if cached is None or cached[0] != token:
-            fn = net.apply if net.is_sharded() else jax.jit(net.apply)
+            with _trace.span("serve.jit_build", bucket=bucket):
+                fn = net.apply if net.is_sharded() else jax.jit(net.apply)
             cached = (token, fn)
             self._jit[bucket] = cached
+            self.stats.inc("jit_builds")
         return cached[1](X)
 
     def _dispatch(self, bucket: int, X) -> tuple[Any, dict]:
@@ -752,8 +772,10 @@ class Server:
         first failure+retry: the batch is immediately retried eagerly."""
         if self.config.jit_dispatch and bucket not in self._jit_broken:
             try:
-                y = self._jitted_apply(bucket, X)
-                jax.block_until_ready(y)
+                with _trace.span("serve.enqueue"):
+                    y = self._jitted_apply(bucket, X)
+                with _trace.span("serve.await"):
+                    jax.block_until_ready(y)
                 self.stats.inc("jit_dispatches")
                 return y, {}
             except Exception as e:
@@ -763,7 +785,8 @@ class Server:
                 self.stats.inc("retries")
                 self._log(f"bucket {bucket}: jitted path fault ({e!r}); "
                           f"falling back to the eager supervised path")
-        return self._supervised_apply(bucket, X)
+        with _trace.span("serve.eager"):
+            return self._supervised_apply(bucket, X)
 
     # ---- supervision: the degrade ladder ---------------------------------
 
