@@ -17,6 +17,11 @@ resource-constrained-CPU setting implies:
     Arrivals coalesce for `batch_wait_s`, are dispatched
     earliest-deadline-first, and are padded up to the smallest covering
     bucket.
+  * **One batch ahead.** On the jitted path the scheduler enqueues the next
+    batch before it awaits the one in flight, so selection, assembly, the
+    host-to-device copy and the answers of one batch overlap the device
+    time of the other; a batch with nothing queued behind it is answered
+    at once.
   * **Deadlines.** Per-request deadlines; requests that expire while queued
     are timeout-cancelled before dispatch (never executed), and responses
     that land past their deadline are flagged `deadline_missed`.
@@ -193,6 +198,24 @@ class Ticket:
         return self.finished_at - self.submitted_at
 
 
+@dataclass(eq=False)
+class _Batch:
+    """One dispatched batch, from assembly to its answers. `y` is the
+    output: un-awaited while `jitted`, computed on the eager path
+    otherwise, None when it has yet to run (eagerly)."""
+
+    tickets: list[Ticket]
+    bucket: int
+    X: Any                          # the padded input, host then device
+    t_select: float                 # selection stamp
+    t0: float                       # dispatch start (before the copy)
+    fails_before: int               # stats.executor_failures at assembly
+    prof: Any                       # the profiler read at assembly
+    y: Any = None
+    layer_times: dict = dataclasses.field(default_factory=dict)
+    jitted: bool = False
+
+
 #: every ServerStats counter, in snapshot order. Each is a live view over
 #: a repro.obs.metrics Counter in the server's own registry ("serve.<name>"),
 #: so attribute reads/writes, the metrics snapshot, and the CI gate all see
@@ -210,6 +233,9 @@ _STAT_COUNTERS = (
     # buckets that fell back to the eager supervised path on their first
     # fault, and jitted callables built (each compiles on its first call).
     "jit_dispatches", "jit_fallbacks", "jit_builds",
+    # jitted batches enqueued while an earlier one was still un-awaited:
+    # its share of jit_dispatches is how often dispatching ahead engages.
+    "dispatched_ahead",
     # continuous re-placement: probation re-probes run, and evicted layers
     # promoted back onto their original algorithm.
     "probation_reprobes", "probation_promotions",
@@ -386,6 +412,9 @@ class Server:
         # serves eagerly (supervised) from then on.
         self._jit: dict[int, tuple[tuple, Any]] = {}
         self._jit_broken: set[int] = set()
+        # when the last batch's result was ready: a batch enqueued behind
+        # it is timed from here, not from its own enqueue.
+        self._t_done = 0.0
         # continuous re-placement: evicted layer -> {clean, need}; the
         # per-layer window doubles on every failed re-probe.
         self._probation: dict[str, dict] = {}
@@ -642,28 +671,33 @@ class Server:
         return self.buckets[-1]
 
     def _loop(self) -> None:
-        """The scheduler thread. Each batch is one `serve.batch` span whose
-        children tile it: serve.select, serve.assemble, serve.h2d, then
-        serve.enqueue + serve.await (jitted) or serve.eager (supervised),
-        serve.d2h, serve.respond. Waiting for work is `serve.idle`."""
+        """The scheduler thread. Each `serve.batch` dispatches the next
+        batch -- serve.select, serve.assemble, serve.h2d, then
+        serve.enqueue (jitted) or serve.eager (supervised) -- and answers
+        the batch dispatched before it: serve.await, serve.d2h,
+        serve.respond. So on the jitted path one batch stays in flight
+        while the host prepares the next. A batch with nothing queued
+        behind it is answered in its own iteration, and the scheduler
+        never waits for work (`serve.idle`) while one is in flight."""
         cfg = self.config
+        ahead: _Batch | None = None
         while True:
             with self._cv:
-                with _trace.span("serve.idle"):
-                    while not self._queue and not self._stop:
-                        self._cv.wait(0.1)
-                    # dynamic batch formation: let a burst coalesce into a
-                    # fuller bucket instead of dispatching singles.
-                    if (0 < len(self._queue) < self.buckets[-1]
-                            and not self._stop and cfg.batch_wait_s > 0):
-                        self._cv.wait(cfg.batch_wait_s)
-                if self._stop and (not self._queue or not self._draining):
+                if ahead is None:
+                    with _trace.span("serve.idle"):
+                        while not self._queue and not self._stop:
+                            self._cv.wait(0.1)
+                        # dynamic batch formation: let a burst coalesce
+                        # into a fuller bucket instead of dispatching
+                        # singles.
+                        if (0 < len(self._queue) < self.buckets[-1]
+                                and not self._stop and cfg.batch_wait_s > 0):
+                            self._cv.wait(cfg.batch_wait_s)
+                if (self._stop and ahead is None
+                        and (not self._queue or not self._draining)):
                     return
             with _trace.span("serve.batch") as span:
-                with _trace.span("serve.select"):
-                    batch, t_select = self._select()
-                if batch:
-                    self._run_batch(batch, t_select, span)
+                ahead = self._step(ahead, span)
 
     def _select(self) -> tuple[list[Ticket], float]:
         """The queue scan and EDF pick: requests cancelled or expired while
@@ -694,58 +728,147 @@ class Server:
             # everything until dispatch start is batch assembly.
             return batch, time.perf_counter()
 
-    def _run_batch(self, batch: list[Ticket], t_select: float,
-                   span) -> None:
+    def _step(self, ahead: _Batch | None, span) -> _Batch | None:
+        """One scheduler iteration: dispatch the next batch, then answer
+        `ahead`, the jitted batch left in flight by the last iteration.
+        Returns the batch this one leaves in flight: the one it
+        dispatched, when that is jitted and more work is queued. A batch
+        bound for the eager supervised path waits until the one ahead is
+        answered. A jitted fault drops every result not yet delivered --
+        the one ahead included -- and those batches are answered from the
+        eager path."""
+        with _trace.span("serve.select"):
+            tickets, t_select = self._select()
+        new = self._assemble(tickets, t_select, span) if tickets else None
+        if new is not None:
+            jitted = self._jit_live(new.bucket)
+            if ahead is not None and not jitted:
+                self._answer(ahead)
+                ahead = None
+            faults = self.stats.jit_fallbacks
+            try:
+                with _trace.span("serve.h2d"):
+                    new.X = jnp.asarray(new.X)
+                new.y, new.layer_times = self._dispatch(new.bucket, new.X)
+            except Exception as e:
+                self._answer_error(new, e)
+                new = None
+            if self.stats.jit_fallbacks != faults:
+                # faulted at enqueue: the batch ran eagerly, and the
+                # result ahead of it is not delivered either
+                if ahead is not None:
+                    ahead.jitted, ahead.y = False, None
+            elif new is not None:
+                new.jitted = jitted
+                if jitted and ahead is not None:
+                    self.stats.inc("dispatched_ahead")
+                    span.set(ahead=1)
+        if ahead is not None and self._answer(ahead) and new is not None:
+            new.jitted, new.y = False, None     # faulted at await
+        if new is None:
+            return None
+        if new.jitted:
+            with self._cv:
+                if self._queue:
+                    return new
+        self._answer(new)
+        return None
+
+    def _assemble(self, tickets: list[Ticket], t_select: float,
+                  span) -> _Batch:
+        """The batch's host input: the smallest covering bucket, its rows
+        copied in and the rest zero."""
         with _trace.span("serve.assemble"):
             prof = _obs_profile.active()   # ONE global read; None = off
-            b = self._bucket_for(len(batch))
+            b = self._bucket_for(len(tickets))
             if span is not _trace.NULL_SPAN:
-                span.set(bucket=b, rows=len(batch), padded=b)
+                span.set(bucket=b, rows=len(tickets), padded=b)
             X = np.zeros((b,) + self.example_shape, self.np_dtype)
-            for i, t in enumerate(batch):
+            for i, t in enumerate(tickets):
                 X[i] = t.x
-            fails_before = self.stats.executor_failures
-            t0 = time.perf_counter()
+            return _Batch(tickets, b, X, t_select, time.perf_counter(),
+                          self.stats.executor_failures, prof)
+
+    def _answer(self, b: _Batch) -> bool:
+        """Await the batch's jitted result, or compute it on the eager
+        supervised path where it has none, copy it back and answer its
+        tickets in order. A fault at the await breaks the bucket's jitted
+        path and the batch reruns eagerly; returns whether one did."""
+        fault = False
         try:
-            with _trace.span("serve.h2d"):
-                X = jnp.asarray(X)
-            y, layer_times = self._dispatch(b, X)
+            if b.jitted:
+                try:
+                    with _trace.span("serve.await"):
+                        jax.block_until_ready(b.y)
+                    self.stats.inc("jit_dispatches")
+                except Exception as e:
+                    self._jit_fault(b.bucket, e)
+                    fault, b.jitted, b.y = True, False, None
+            if b.y is None:
+                with _trace.span("serve.eager"):
+                    b.y, b.layer_times = self._supervised_apply(b.bucket,
+                                                                b.X)
         except Exception as e:
-            # ladder exhausted: answer every ticket with the error --
-            # failed, but never silently dropped.
-            with _trace.span("serve.respond"):
-                for t in batch:
-                    if t._finish("error", error=e):
-                        self.stats.inc("failed")
-                self.stats.inc("batches")
-                if prof is not None:
-                    prof.serve_batch_error(bucket=b, batch=batch, error=e)
-            return
+            self._answer_error(b, e)
+            return fault
         t1 = time.perf_counter()
         with _trace.span("serve.d2h"):
-            y = np.asarray(y)
+            y = np.asarray(b.y)
+        b.X = b.y = None            # an answered batch holds no device memory
         with _trace.span("serve.respond"):
-            dt = t1 - t0
+            # service time: from the later of the batch's dispatch and the
+            # result before it, so a batch enqueued ahead is not charged
+            # the device time of the one it waited behind
+            dt = t1 - max(b.t0, self._t_done)
+            self._t_done = t1
             a = self.config.ewma_alpha
             self._service_ewma = (dt if self._service_ewma is None
                                   else (1 - a) * self._service_ewma + a * dt)
-            self._observe_stragglers(b, dt, layer_times)
+            self._observe_stragglers(b.bucket, dt, b.layer_times)
             now = time.perf_counter()
-            for i, t in enumerate(batch):
+            for i, t in enumerate(b.tickets):
                 if t.deadline is not None and t.deadline < now:
                     t.deadline_missed = True
                     self.stats.inc("deadline_missed")
                 if t._finish("ok", value=y[i]):
                     self.stats.inc("completed")
             self.stats.inc("batches")
-            self.stats.bump_bucket(b)
-            if prof is not None:
-                prof.serve_batch(bucket=b, batch=batch, t_select=t_select,
-                                 t0=t0, t1=t1)
-            if self.stats.executor_failures == fails_before:
+            self.stats.bump_bucket(b.bucket)
+            if b.prof is not None:
+                b.prof.serve_batch(bucket=b.bucket, batch=b.tickets,
+                                   t_select=b.t_select, t0=b.t0, t1=t1)
+            if self.stats.executor_failures == b.fails_before:
                 self._note_clean_batch()
+        return fault
+
+    def _answer_error(self, b: _Batch, e: BaseException) -> None:
+        """Ladder exhausted: answer every ticket with the error -- failed,
+        but never silently dropped."""
+        with _trace.span("serve.respond"):
+            for t in b.tickets:
+                if t._finish("error", error=e):
+                    self.stats.inc("failed")
+            self.stats.inc("batches")
+            if b.prof is not None:
+                b.prof.serve_batch_error(bucket=b.bucket, batch=b.tickets,
+                                         error=e)
 
     # ---- dispatch: the jitted happy path ---------------------------------
+
+    def _jit_live(self, bucket: int) -> bool:
+        """Whether the bucket's batches take the jitted path."""
+        return self.config.jit_dispatch and bucket not in self._jit_broken
+
+    def _jit_fault(self, bucket: int, e: BaseException) -> None:
+        """A fault on the bucket's jitted path, at enqueue or await: the
+        bucket serves eagerly (supervised) from now on, and the fault
+        counts as the batch's first failure and retry."""
+        self._jit_broken.add(bucket)
+        self.stats.inc("jit_fallbacks")
+        self.stats.inc("executor_failures")
+        self.stats.inc("retries")
+        self._log(f"bucket {bucket}: jitted path fault ({e!r}); "
+                  f"falling back to the eager supervised path")
 
     def _jitted_apply(self, bucket: int, X):
         """One batch through the jitted apply. The callable is cached per
@@ -766,25 +889,17 @@ class Server:
         return cached[1](X)
 
     def _dispatch(self, bucket: int, X) -> tuple[Any, dict]:
-        """Jitted happy path until the bucket's first fault, then the eager
-        supervised path (per-layer hooks + the degrade ladder) for that
-        bucket from then on. The jitted-path failure counts as the batch's
-        first failure+retry: the batch is immediately retried eagerly."""
-        if self.config.jit_dispatch and bucket not in self._jit_broken:
+        """The batch's computation: the bucket's jitted apply, enqueued
+        and returned un-awaited, until the bucket's first fault; from then
+        on the eager supervised path (per-layer hooks + the degrade
+        ladder) for that bucket, which returns a finished result. A fault
+        at enqueue runs the batch eagerly at once."""
+        if self._jit_live(bucket):
             try:
                 with _trace.span("serve.enqueue"):
-                    y = self._jitted_apply(bucket, X)
-                with _trace.span("serve.await"):
-                    jax.block_until_ready(y)
-                self.stats.inc("jit_dispatches")
-                return y, {}
+                    return self._jitted_apply(bucket, X), {}
             except Exception as e:
-                self._jit_broken.add(bucket)
-                self.stats.inc("jit_fallbacks")
-                self.stats.inc("executor_failures")
-                self.stats.inc("retries")
-                self._log(f"bucket {bucket}: jitted path fault ({e!r}); "
-                          f"falling back to the eager supervised path")
+                self._jit_fault(bucket, e)
         with _trace.span("serve.eager"):
             return self._supervised_apply(bucket, X)
 

@@ -353,6 +353,33 @@ def test_profiler_trace_holds_serve_phase_spans(params, xs, tmp_path):
         assert sum(k[2] - k[1] for k in kids) >= 0.5 * (b - a)
 
 
+def test_lone_requests_bypass_dispatching_ahead(params, xs):
+    """One request at a time leaves nothing queued behind a batch: no
+    batch is enqueued ahead, each batch is awaited in the serve.batch that
+    enqueued it, and the scheduler never waits for work (serve.idle) while
+    a batch is in flight."""
+    with Server(params, SPECS, res=RES,
+                config=make_cfg(jit_dispatch=True)) as srv:
+        serve_n(srv, xs, 2)
+        tr = trace.enable()
+        serve_n(srv, xs, 6)
+        srv.stop()
+    trace.disable()
+    assert srv.stats.dispatched_ahead == 0
+    assert srv.stats.jit_dispatches == 8
+    spans = tr.spans()
+    batches = [s for s in spans if s.name == "serve.batch"]
+    enqueued = [s for s in spans if s.name == "serve.enqueue"]
+    awaited = [s for s in spans if s.name == "serve.await"]
+    idle = [s for s in spans if s.name == "serve.idle"]
+    assert len(batches) == len(enqueued) == len(awaited) == 6
+    assert idle
+    for b, e, a in zip(batches, enqueued, awaited):
+        assert b.t0 <= e.t0 <= e.t1 <= a.t0 <= a.t1 <= b.t1
+        assert "ahead" not in b.args
+        assert not any(i.t0 < a.t1 and e.t0 < i.t1 for i in idle)
+
+
 def test_jit_builds_count_rebuilt_programs(params, xs):
     """jit_builds counts each bucket's jitted callable once, and again
     when a re-placed layer forces a rebuild."""

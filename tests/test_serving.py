@@ -5,6 +5,7 @@ deterministic fault injectors in repro.runtime.inject, plus the per-array
 artifact checksum gate in repro.core.compile."""
 
 import os
+import time
 
 import numpy as np
 import pytest
@@ -360,3 +361,123 @@ def test_batches_form_across_buckets(params, xs):
     srv.stop()
     assert srv.stats.bucket_batches == {4: 1, 2: 1}
     assert srv.stats.completed == 6 and srv.stats.in_flight == 0
+
+
+# ---------------------------------------------------------------------------
+# dispatching ahead: one jitted batch in flight while the next is prepared
+# ---------------------------------------------------------------------------
+
+def burst(rng, n=12):
+    """`n` distinct inputs: three batches of the largest bucket (4)."""
+    return [rng.standard_normal((RES, RES, 3)).astype(np.float32)
+            for _ in range(n)]
+
+
+def serve_burst(srv, xs, *, warmup=True):
+    """Admit every input before the scheduler starts, so the batches form
+    back to back, then answer them all."""
+    tickets = [srv.submit(x) for x in xs]
+    srv.start(warmup=warmup)
+    ys = [t.result(timeout=120) for t in tickets]
+    srv.stop()
+    return tickets, ys
+
+
+def test_dispatch_ahead_answers_each_ticket_its_own_rows(params, rng):
+    """A burst of three largest-bucket batches runs one batch ahead on the
+    jitted path, and every ticket still gets the answer of its own input,
+    as the unpipelined eager path gives it."""
+    xs = burst(rng)
+    cfg = make_cfg(queue_capacity=16)
+    ref = Server(params, SPECS, res=RES, algorithm="winograd",
+                 config=make_cfg(queue_capacity=16, jit_dispatch=False))
+    _, want = serve_burst(ref, xs)
+    srv = Server(params, SPECS, res=RES, algorithm="winograd", config=cfg)
+    tickets, ys = serve_burst(srv, xs)
+    s = srv.stats
+    # the second and third batches were enqueued behind an un-awaited one
+    assert s.dispatched_ahead == 2 and s.jit_dispatches == 3
+    assert s.bucket_batches == {4: 3}
+    assert s.in_flight == 0 and s.failed == 0
+    assert all(t.status == "ok" for t in tickets)
+    for y, w in zip(ys, want):
+        assert_close(y, w, tol=1e-5)
+
+
+@pytest.mark.parametrize("where", ["enqueue", "await"])
+def test_jit_fault_with_a_batch_ahead_loses_no_request(params, rng,
+                                                       monkeypatch, where):
+    """A jitted fault while a batch is ahead -- raised as the second batch
+    is enqueued, or as the first is awaited -- breaks the bucket once, and
+    both batches (and the third, behind them) are answered through the
+    eager supervised path: no request fails or stays pending."""
+    xs = burst(rng)
+    srv = Server(params, SPECS, res=RES, algorithm="winograd",
+                 config=make_cfg(queue_capacity=16))
+    srv.warmup()
+    eager_rows = []
+    supervised = srv._supervised_apply
+
+    def recording(bucket, X):
+        eager_rows.extend(np.asarray(X))
+        return supervised(bucket, X)
+    monkeypatch.setattr(srv, "_supervised_apply", recording)
+    if where == "enqueue":
+        token, fn = srv._jit[4]
+        calls = []
+
+        def apply(X):
+            calls.append(None)
+            if len(calls) == 2:
+                raise RuntimeError("injected fault at enqueue")
+            return fn(X)
+        srv._jit[4] = (token, apply)
+    else:
+        block = jax.block_until_ready
+        fired = []
+
+        def await_once(y):
+            if not fired:
+                fired.append(None)
+                raise RuntimeError("injected fault at await")
+            return block(y)
+        monkeypatch.setattr(jax, "block_until_ready", await_once)
+    tickets, ys = serve_burst(srv, xs, warmup=False)
+    s = srv.stats
+    assert s.jit_fallbacks == 1 and s.failed == 0 and s.in_flight == 0
+    assert s.jit_dispatches == 0 and s.completed == len(xs)
+    assert s.dispatched_ahead == (1 if where == "await" else 0)
+    assert all(t.done() and t.status == "ok" for t in tickets)
+    # every batch ran eagerly: each input went through the eager path once
+    assert len(eager_rows) == len(xs)
+    assert sorted(next(i for i, x in enumerate(xs) if np.array_equal(r, x))
+                  for r in eager_rows) == list(range(len(xs)))
+    for y, want in zip(ys, oracle_outputs(params, xs)):
+        assert_close(y, want)
+
+
+def test_service_time_excludes_the_batch_ahead(params, rng, monkeypatch):
+    """A batch enqueued behind another is timed from the other's result,
+    not from its own enqueue, so straggler detection and the service
+    EWMA see one batch's device time, not two. Each await is made to take
+    0.1 s, standing in for the device."""
+    srv = Server(params, SPECS, res=RES, algorithm="winograd",
+                 config=make_cfg(queue_capacity=16))
+    srv.warmup()
+    block = jax.block_until_ready
+
+    def slow_device(y):
+        time.sleep(0.1)
+        return block(y)
+    monkeypatch.setattr(jax, "block_until_ready", slow_device)
+    times = []
+    observe = srv._observe_stragglers
+
+    def recording(bucket, dt, layer_times):
+        times.append(dt)
+        return observe(bucket, dt, layer_times)
+    monkeypatch.setattr(srv, "_observe_stragglers", recording)
+    serve_burst(srv, burst(rng), warmup=False)
+    assert srv.stats.dispatched_ahead == 2 and len(times) == 3
+    # two batches' device time would read 0.2 s
+    assert all(0.1 <= dt < 0.17 for dt in times), times
