@@ -1,23 +1,20 @@
-"""kernel_roofline: the conv layers' least time (bench/flops.py) summed
-over the batches dispatched in the traced window -- `conv_roofline`'s
-numerator -- as a share of the device time of the ops that belong to the
-conv-kind nodes (conv2d, separable, inverted_residual) by their kernel
-names or named scopes. Pools, the dense head and ops outside every node
-stay out of the denominator."""
+"""kernel_roofline: the least time (bench/flops.py) of the layers whose
+program node has device time in the traced window, summed over the
+window's batches, as a share of those nodes' device time (their kernels by
+name, their ops by named scope). A layer whose node the trace does not
+attribute, such as one that runs on XLA's own ops, leaves both sides, and
+so do pools and ops outside every node."""
 
-from bench import flops
-
-CONV_KINDS = ("conv2d", "separable", "inverted_residual")
+from bench import flops, trace_reduce
 
 
 def read(run):
     nodes = (run.trace or {}).get("node_device_s")
     if not nodes or not run.batches:
         return None
-    conv_s = sum(s for n, s in nodes.items()
-                 if n.split(":", 1)[0] in CONV_KINDS)
-    if not conv_s:
+    device = {trace_reduce.node_id(k): s for k, s in nodes.items()}
+    least = flops.least_time_by_node(run.layers, run.batches, run.peak)
+    hit = [n for n in least if device.get(n)]
+    if not hit:
         return None
-    least = sum(n * flops.least_time_s(run.layers, b, run.peak)
-                for b, n in run.batches.items())
-    return 100.0 * least / conv_s
+    return 100.0 * sum(least[n] for n in hit) / sum(device[n] for n in hit)

@@ -62,19 +62,24 @@ def forward(params, x, cfg, round_to=None):
 
 
 def layers(cfg) -> list[dict]:
-    """Conv and dense layers of one image, with their input sizes."""
+    """Conv and dense layers of one image, with their input sizes and
+    the program node each runs in: an inverted residual's expand, depthwise
+    and projection all run in its fused node `ir<i>`."""
     side = cfg["res"]
-    out = [ops.conv_layer(side, side, cfg["c_in"], cfg["stem"], 3, 2)]
+    out = [ops.conv_layer("conv1", side, side, cfg["c_in"], cfg["stem"], 3,
+                          3, 2)]
     side = ops.out_size(side, 2)
     c_last = cfg["stem"]
-    for _, c_in, c_out, t, s in _blocks(cfg):
+    for name, c_in, c_out, t, s in _blocks(cfg):
         ce = c_in * t
         if t != 1:
-            out.append(ops.conv_layer(side, side, c_in, ce, 1))
-        out.append(ops.conv_layer(side, side, ce, ce, 3, s, groups=ce))
+            out.append(ops.conv_layer(name, side, side, c_in, ce, 1, 1))
+        out.append(ops.conv_layer(name, side, side, ce, ce, 3, 3, s,
+                                  groups=ce))
         side = ops.out_size(side, s)
-        out.append(ops.conv_layer(side, side, ce, c_out, 1))
+        out.append(ops.conv_layer(name, side, side, ce, c_out, 1, 1))
         c_last = c_out
-    out.append(ops.conv_layer(side, side, c_last, cfg["head"], 1))
-    out.append(ops.dense_layer(cfg["head"], cfg["classes"]))
+    out.append(ops.conv_layer("conv_head", side, side, c_last, cfg["head"],
+                              1, 1))
+    out.append(ops.dense_layer("fc", cfg["head"], cfg["classes"]))
     return out

@@ -74,12 +74,16 @@ def max_pool(x, k: int, stride: int):
                                  (1, stride, stride, 1), "VALID")
 
 
-def conv_layer(h: int, w: int, c_in: int, c_out: int, k: int,
-               stride: int = 1, groups: int = 1) -> dict:
-    """One entry of a network's layer inventory (input size h x w)."""
-    return {"op": "conv", "h": h, "w": w, "c_in": c_in, "c_out": c_out,
-            "k": k, "stride": stride, "groups": groups}
+def conv_layer(node: str, h: int, w: int, c_in: int, c_out: int, kh: int,
+               kw: int, stride: int = 1, groups: int = 1,
+               padding: str = "SAME") -> dict:
+    """One entry of a network's layer inventory: a kh x kw filter over an
+    h x w input, run inside the program node `node` (its id in
+    `NetworkPlan.describe()`)."""
+    return {"op": "conv", "node": node, "h": h, "w": w, "c_in": c_in,
+            "c_out": c_out, "kh": kh, "kw": kw, "stride": stride,
+            "groups": groups, "padding": padding}
 
 
-def dense_layer(n_in: int, n_out: int) -> dict:
-    return {"op": "dense", "n_in": n_in, "n_out": n_out}
+def dense_layer(node: str, n_in: int, n_out: int) -> dict:
+    return {"op": "dense", "node": node, "n_in": n_in, "n_out": n_out}

@@ -53,14 +53,15 @@ def forward(params, x, cfg, round_to=None):
 
 
 def layers(cfg) -> list[dict]:
-    """Conv and dense layers of one image, with their input sizes."""
+    """Conv and dense layers of one image, with their input sizes and
+    the program node each runs in (named as the parameters are)."""
     out, side = [], cfg["res"]
     for name, c_in, c_out, last in _convs(cfg):
-        out.append(ops.conv_layer(side, side, c_in, c_out, 3))
+        out.append(ops.conv_layer(name, side, side, c_in, c_out, 3, 3))
         if last:
             side //= 2
     n_in = side * side * cfg["blocks"][-1][1]
-    for n_out in cfg["fc"]:
-        out.append(ops.dense_layer(n_in, n_out))
+    for name, n_out in zip(_fcs(cfg), cfg["fc"]):
+        out.append(ops.dense_layer(name, n_in, n_out))
         n_in = n_out
     return out

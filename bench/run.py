@@ -16,9 +16,10 @@ file of its own. One run, in one process:
      reference (`bench/check.py`);
   7. prints the metrics of the cell: the end-to-end ones, or with
      `--trace 1` the per-layer ones read from a profiler trace of the
-     window, which is then at most `TRACE_WINDOW_S` long. The numbers
-     compared go last on stderr, and the result is the last line on
-     stdout.
+     window, which is then at most `TRACE_WINDOW_S` long, after a
+     `bench nodes` line (`trace_reduce.summary`: host phases, and device
+     time and roofline share per program node). The numbers compared go
+     last on stderr, and the result is the last line on stdout.
 """
 
 from __future__ import annotations
@@ -44,7 +45,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import check, flops, load, manifest  # noqa: E402
+from bench import check, flops, load, manifest, trace_reduce  # noqa: E402
 
 #: images per block of the reference
 REF_BLOCK = 8
@@ -298,7 +299,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     reduction = None
     if trace:
-        from bench import trace_reduce
         t = time.perf_counter()
         jax.profiler.stop_trace()
         stop_s = time.perf_counter() - t
@@ -332,8 +332,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
          memory_stats=dev_mem[0])
     note("server", **{k: stats[k] for k in (
         "admitted", "rejected", "completed", "failed", "in_flight",
-        "jit_dispatches", "jit_fallbacks", "replacements", "retries",
-        "bucket_batches")})
+        "jit_dispatches", "dispatched_ahead", "jit_fallbacks",
+        "replacements", "retries", "bucket_batches")})
 
     t = time.perf_counter()
     answered = {r.image for r in window.requests
@@ -350,6 +350,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         latencies_s=latencies, bytes_in_use=bytes_in_use,
         trace=reduction,
         batches=batches_between(marks.stats_open, marks.stats_close))
+    if reduction is not None:
+        note("nodes", **trace_reduce.summary(
+            reduction, flops.least_time_by_node(ctx.layers, ctx.batches,
+                                                peak)))
     metrics = {}
     for m in manifest.metrics(man, workload, trace):
         v = manifest.reader(m["name"])(ctx)

@@ -1,7 +1,8 @@
-"""bench/trace_spans.py and the metrics that read it (host_ms, h2d_ms,
-kernel_roofline): on a hand-made trace with known answers, on a trace of
-the program recorded on a TPU v5e (bench/tests/data/), and through a whole
-traced run of bench/tools/trace_nodes.py on this CPU."""
+"""The program's spans and node names in bench/trace_reduce.py, and the
+metrics that read them (host_ms, h2d_ms, kernel_roofline): on hand-made
+traces with known answers, on a trace of the program recorded on a TPU v5e
+(bench/tests/data/), and through whole traced runs of bench/run.py on this
+CPU."""
 
 import json
 import os
@@ -18,13 +19,17 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import flops, manifest  # noqa: E402
 from bench import trace_reduce as tr  # noqa: E402
-from bench import trace_spans as ts  # noqa: E402
+from bench.reference import ops  # noqa: E402
 
 MS = 1_000_000
 #: a window of the mbv2.offline cell's first three batches (bucket 8),
-#: recorded by `bench/tools/trace_nodes.py --keep` on a TPU v5e; the
+#: cut by `trace_reduce.first_batches` from a traced run on a TPU v5e; the
 #: runtime's per-chunk "Transpose" host events are left out for size
 SPANS_TRACE = os.path.join(HERE, "data", "v5e_mbv2_spans_trace.json")
+#: the four keys of each recorded trace's reduction as the reduction gave
+#: them before it read spans and node names
+OLD_KEYS = ("window_s", "busy_s", "device_ops", "idle_gaps")
+OLD_READINGS = os.path.join(HERE, "data", "v5e_mbv2_reductions.json")
 JIT_PHASES = ["select", "assemble", "h2d", "enqueue", "await", "d2h",
               "respond"]
 
@@ -79,12 +84,12 @@ def _hand_made():
     ("%copy.26", None, None),
 ])
 def test_node_of(op, stats, node):
-    assert ts.node_of(op, stats) == node
+    assert tr.node_of(op, stats) == node
 
 
 def test_hand_made_trace():
     t = _hand_made()
-    r = ts.reduce(t)
+    r = tr.reduce(t)
     assert r["serve_batches"] == [{
         "batch": pytest.approx(0.058), "select": pytest.approx(0.001),
         "h2d": pytest.approx(0.006), "await": pytest.approx(0.047),
@@ -95,20 +100,25 @@ def test_hand_made_trace():
                                   "conv2d:c3": pytest.approx(0.004)}
     assert dict(r["unattributed_ops"]) == {"%pad.0": pytest.approx(0.005),
                                            "%fusion.2": pytest.approx(0.005)}
-    # the old reduction reads the same trace as before
-    assert tr.reduce(t)["busy_s"] == pytest.approx(0.043)
+    assert r["busy_s"] == pytest.approx(0.043)
 
 
 def test_recorded_trace_keeps_the_old_reduction():
-    """The new keys leave trace_reduce's own reading as it was: on the
-    recorded spans trace with and without them, and on the trace the
-    benchmark's tests already keep."""
+    """Reading spans and node names leaves the device reduction as it
+    was: on both recorded traces, with and without spans and node names,
+    equal to the last digit to what it read before it read them."""
+    with open(OLD_READINGS) as f:
+        before = json.load(f)
     t = _recorded()
     bare = {"devices": [{"ops": d["ops"]} for d in t["devices"]],
             "host": t["host"]}
-    assert tr.reduce(t) == tr.reduce(bare)
+    for trace in (t, bare):
+        r = tr.reduce(trace)
+        assert {k: r[k] for k in OLD_KEYS} == \
+            before["v5e_mbv2_spans_trace.json"]
     with open(os.path.join(HERE, "data", "v5e_mbv2_trace.json")) as f:
         old = tr.reduce(json.load(f))
+    assert {k: old[k] for k in OLD_KEYS} == before["v5e_mbv2_trace.json"]
     assert old["window_s"] == pytest.approx(0.03)
     assert old["busy_s"] == pytest.approx(0.004736851)
     assert old["device_ops"][0] == ["%winograd_strided_streamed.1",
@@ -119,7 +129,7 @@ def test_recorded_trace_keeps_the_old_reduction():
 
 def test_recorded_trace_batches_and_nodes():
     t = _recorded()
-    r = ts.reduce(t)
+    r = tr.reduce(t)
     assert len(r["serve_batches"]) == 3
     for row in r["serve_batches"]:
         assert [k for k in row if k != "batch"] == JIT_PHASES
@@ -129,7 +139,7 @@ def test_recorded_trace_batches_and_nodes():
     assert set(nodes) == set(t["devices"][0]["op_nodes"].values())
     assert len(nodes) == 18                  # conv1 and ir1 .. ir17
     # every op is a node's or listed outside, once
-    busy = tr.reduce(t)["busy_s"]
+    busy = r["busy_s"]
     outside = sum(s for _, s in r["unattributed_ops"])
     assert sum(nodes.values()) + outside <= busy * 1.001
     assert sum(nodes.values()) > 0.5 * busy
@@ -146,8 +156,7 @@ def _ctx(reduction, batches):
 
 
 def test_readers_on_recorded_trace():
-    t = _recorded()
-    reduction = {**tr.reduce(t), **ts.reduce(t)}
+    reduction = tr.reduce(_recorded())
     run = _ctx(reduction, {8: 3})
     rows = reduction["serve_batches"]
     host = manifest.reader("host_ms")(run)
@@ -157,31 +166,80 @@ def test_readers_on_recorded_trace():
     assert manifest.reader("h2d_ms")(run) == pytest.approx(
         1e3 * statistics.median(r["h2d"] for r in rows))
     kernel = manifest.reader("kernel_roofline")(run)
-    conv = manifest.reader("conv_roofline")(run)
-    assert conv < kernel < 100
-    conv_s = sum(s for n, s in reduction["node_device_s"].items()
-                 if n.split(":")[0] in ("conv2d", "inverted_residual"))
-    assert kernel == pytest.approx(conv * reduction["busy_s"] / conv_s)
+    assert 0 < kernel < 100
+    # conv_head and fc run on XLA's own ops, which carry no node: their
+    # least time leaves the numerator as their device time is not in the
+    # denominator
+    device = {tr.node_id(n): s
+              for n, s in reduction["node_device_s"].items()}
+    assert set(device) == {"conv1", *(f"ir{i}" for i in range(1, 18))}
+    least = flops.least_time_by_node(run.layers, run.batches, run.peak)
+    assert kernel == pytest.approx(
+        100 * sum(least[n] for n in device) / sum(device.values()))
 
 
 def test_readers_read_nothing_without_the_new_keys():
+    """A trace without the program's spans and node names."""
     t = _recorded()
-    run = _ctx(tr.reduce(t), {8: 3})
+    bare = {"devices": [{"ops": d["ops"]} for d in t["devices"]],
+            "host": t["host"]}
+    run = _ctx(tr.reduce(bare), {8: 3})
     for name in ("host_ms", "h2d_ms", "kernel_roofline"):
         assert manifest.reader(name)(run) is None
 
 
-def test_tool_run_on_cpu(monkeypatch, tmp_path):
-    """A whole traced run through bench/tools/trace_nodes.py at a small
-    size on this CPU, with the look for a chip skipped: the host metrics
-    read the program's spans (a CPU trace has no device plane, so
-    kernel_roofline reads nothing), the kept window holds its batches,
-    and the harness is left as it was."""
+def test_node_without_attributed_ops_leaves_both_sides():
+    """Two alike convs; only `a` runs in a named kernel, `b` on ops that
+    carry no node. `b`'s least time stays out of the numerator as its
+    device time is out of the denominator, so the reading is `a`'s own
+    share (80%), where counting `b`'s least time would read 160%."""
+    peak = flops.peaks("TPU v5 lite")
+    layers = [ops.conv_layer(n, 56, 56, 64, 64, 3, 3) for n in "ab"]
+    least = flops.layer_time_s(layers[0], 8, peak)
+    dur = round(1.25 * least * 1e9)
+    kernel = "%winograd_streamed__conv2d__a.1"
+    trace = {"devices": [{"ops": [[kernel, 0, dur], ["%fusion.1", dur,
+                                                     2 * dur]],
+                          "op_nodes": {kernel: "conv2d:a"}}],
+             "host": [["bench.window", 0, 3 * dur]]}
+    reduction = tr.reduce(trace)
+    assert reduction["node_device_s"] == {"conv2d:a": pytest.approx(
+        dur * 1e-9)}
+    run = SimpleNamespace(trace=reduction, batches={8: 1}, layers=layers,
+                          peak=peak)
+    reading = manifest.reader("kernel_roofline")(run)
+    assert reading == pytest.approx(100 * least / (dur * 1e-9))
+    assert 79.9 < reading <= 100
+    nodes = tr.summary(reduction, flops.least_time_by_node(
+        layers, {8: 1}, peak))["nodes"]
+    assert nodes == [["conv2d:a", pytest.approx(dur * 1e-9),
+                      pytest.approx(reading)]]
+
+
+def test_first_batches_cuts_the_window():
+    t = _recorded()
+    cut = tr.first_batches(t, 2)
+    r = tr.reduce(cut)
+    assert len(r["serve_batches"]) == 2
+    assert r["serve_batches"] == tr.reduce(t)["serve_batches"][:2]
+    assert r["window_s"] < tr.reduce(t)["window_s"]
+
+
+@pytest.mark.parametrize("source", ["cpu_trace", "recorded_trace"])
+def test_traced_run_on_cpu(monkeypatch, tmp_path, capsys, source):
+    """A whole traced run of bench/run.py at a small size on this CPU,
+    with the look for a chip skipped, reading mbv2.offline's per-layer
+    metrics. On the CPU's own trace the host
+    metrics read the program's spans, and kernel_roofline reads nothing (a
+    CPU trace has no device plane). With the recorded TPU trace in its
+    place, every per-layer metric reads, and the `bench nodes` line names
+    the recorded nodes with their roofline shares."""
     from bench import run as bench_run
-    from bench.tools import trace_nodes
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(bench_run, "require_chips", lambda chips: (
         jax.devices(), flops.peaks("TPU v5 lite")))
+    if source == "recorded_trace":
+        monkeypatch.setattr(tr, "load", lambda tdir: _recorded())
     knobs = {k: getattr(jax.config, k) for k in (
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes",
@@ -189,21 +247,35 @@ def test_tool_run_on_cpu(monkeypatch, tmp_path):
     cfg = dict(manifest.config("mbv2_224"), res=32, algorithm="auto")
     traffic = {"kind": "closed", "in_flight": 4, "pool": 8, "buckets": [2],
                "warm_requests": 4}
-    keep = str(tmp_path / "keep.json")
     try:
-        res, summary = trace_nodes.run("small", 2**31 + 7, 0.5,
-                                       cell_files=(cfg, traffic), keep=keep)
+        res, _ = bench_run.run("mbv2.offline", 2**31 + 7, 0.5, True,
+                               cell_files=(cfg, traffic))
     finally:
         for k, v in knobs.items():
             jax.config.update(k, v)
+    lines = capsys.readouterr().out.splitlines()
+    nodes = [json.loads(line[len("bench nodes "):]) for line in lines
+             if line.startswith("bench nodes ")]
+    assert len(nodes) == 1
+    nodes = nodes[0]
     assert res["correct"]
     m = res["metrics"]
-    assert m["host_ms"]["value"] > 0 and m["h2d_ms"]["value"] > 0
-    assert "kernel_roofline" not in m
-    assert summary["batches"] > 0
-    assert set(summary["phase_ms_p50"]) == {"batch", *JIT_PHASES}
-    with open(keep) as f:
-        kept = ts.reduce(json.load(f))
-    assert len(kept["serve_batches"]) == trace_nodes.KEEP_BATCHES
-    assert tr.load is not None and tr.load.__module__ == "bench.trace_reduce"
-    assert manifest.metrics.__module__ == "bench.manifest"
+    assert set(m) >= {"host_ms", "h2d_ms", "idle_share"}
+    if source == "cpu_trace":
+        assert m["host_ms"]["value"] > 0 and m["h2d_ms"]["value"] > 0
+        assert "kernel_roofline" not in m
+        assert nodes["batches"] > 0 and nodes["nodes"] == []
+        assert set(nodes["phase_ms_p50"]) == {"batch", *JIT_PHASES}
+        return
+    recorded = tr.reduce(_recorded())
+    assert res["device"]["busy_s"] == recorded["busy_s"]
+    assert m["host_ms"]["value"] == manifest.reader("host_ms")(
+        SimpleNamespace(trace=recorded))
+    assert 0 < m["kernel_roofline"]["value"] <= 100
+    assert nodes["batches"] == 3
+    assert nodes["node_share"] == pytest.approx(
+        100 * sum(recorded["node_device_s"].values()) / recorded["busy_s"])
+    assert [n[0] for n in nodes["nodes"]][:2] == ["conv2d:conv1",
+                                                  "inverted_residual:ir1"]
+    assert all(0 < share <= 100 for _, _, share in nodes["nodes"])
+    assert nodes["unattributed_ops"][0][0] == "%pad.0"
